@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import default_interpret, pad_axis
+from repro.kernels.common import default_interpret, pad_axis, u32_to_f32
 from repro.kernels.quant.ref import quant_levels
 
 _INV_2_32 = float(2.0 ** -32)
@@ -40,7 +40,7 @@ def _ef_kernel(z_ref, h_ref, u_ref, s_ref, o_ref, *, L: int,
     delta = s * (1.0 / L)  # mul-by-reciprocal, matching ref (see ref.py)
     safe = jnp.where(delta > 0, delta, 1.0)
     if stochastic:
-        u = u_ref[...].astype(jnp.float32) * _INV_2_32
+        u = u32_to_f32(u_ref[...]) * _INV_2_32
     else:
         u = 0.5
     q = jnp.floor(r / safe + u)

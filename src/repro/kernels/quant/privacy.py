@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import default_interpret, pad_axis
+from repro.kernels.common import default_interpret, pad_axis, u32_to_f32
 from repro.kernels.quant.ref import quant_levels
 
 _INV_2_32 = float(2.0 ** -32)
@@ -46,7 +46,7 @@ def _private_cols_kernel(x_ref, f_ref, uq_ref, lap_ref, cf_ref, b_ref, s_ref,
     y = x * cf + b * lap
     delta = s * (1.0 / L)  # mul-by-reciprocal, matching ref (see ref.py)
     safe = jnp.where(delta > 0, delta, 1.0)
-    u = uq_ref[...].astype(jnp.float32) * _INV_2_32
+    u = u32_to_f32(uq_ref[...]) * _INV_2_32
     q = jnp.floor(y / safe + u)
     q = jnp.clip(q, -L, L)
     dq = jnp.where(delta > 0, q * safe, 0.0).astype(o_ref.dtype)

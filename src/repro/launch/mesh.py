@@ -8,21 +8,32 @@ before any jax import (launch/dryrun.py lines 1-2).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    The repo's sharding code constrains with ``with_sharding_constraint``
+    and mixes sharded with replicated operands, which needs Auto axes;
+    newer JAX defaults ``make_mesh`` axes to ``Explicit``.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """v5e pod mesh: 16x16 = 256 chips per pod; 2 pods multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 2, *,
                    multi_pod: bool = False):
     """Small mesh for CPU tests (requires forced host device count)."""
     if multi_pod:
-        return jax.make_mesh((2, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return make_mesh((2, n_data, n_model), ("pod", "data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 def client_axes(mesh) -> tuple:

@@ -31,14 +31,14 @@ def main(argv=None):
 
     from repro import configs
     from repro.core import distributed as dist_mod
-    from repro.launch.mesh import client_axes, make_production_mesh
+    from repro.launch.mesh import make_mesh, make_production_mesh
     from repro.launch.steps import _named, serve_activation_rules
     from repro.models.registry import get_model
     from repro.sharding.rules import axis_rules
 
     if args.mesh_shape:
         dd, mm = (int(x) for x in args.mesh_shape.split(","))
-        mesh = jax.make_mesh((dd, mm), ("data", "model"))
+        mesh = make_mesh((dd, mm), ("data", "model"))
     else:
         mesh = make_production_mesh()
 

@@ -66,6 +66,7 @@ import argparse
 import json
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.spec import (
     AlgorithmSpec,
     CodecSpec,
@@ -400,6 +401,7 @@ def main(argv=None):
     ap.add_argument("--json", default=None,
                     help="write the summary dict to this path")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # legacy-surface defaults (the spec file's values win under --spec)
     args.engine = args.engine_flag or "eager"

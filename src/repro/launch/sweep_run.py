@@ -169,6 +169,13 @@ def _execute_one(payload) -> tuple[str, str, str | None]:
 # the driver
 # ---------------------------------------------------------------------------
 
+def _cpu_only() -> bool:
+    """JAX_PLATFORMS pins the CPU. Read from the environment, so no
+    backend starts in the parent."""
+    plats = os.environ.get("JAX_PLATFORMS", "")
+    return [p.strip() for p in plats.split(",") if p.strip()] == ["cpu"]
+
+
 @dataclass
 class SweepResult:
     """Outcome of one :func:`execute_cells` invocation."""
@@ -200,8 +207,15 @@ def execute_cells(cells: Sequence, *, out_dir, jobs: int = 1,
     ctx) fingerprint is skipped, unless ``rerun`` forces re-execution.
     ``max_cells`` caps how many pending cells this invocation attempts
     (the resume test's controlled kill point). ``progress(name, status,
-    err, done, total)`` is called per finished cell.
+    err, done, total)`` is called per finished cell. ``jobs > 1`` is
+    refused unless ``JAX_PLATFORMS=cpu``: an accelerator serves one
+    process, so the other workers would fail or hang.
     """
+    if jobs > 1 and not _cpu_only():
+        raise ValueError(
+            f"jobs={jobs} starts worker processes, and an accelerator "
+            f"serves one process at a time: set JAX_PLATFORMS=cpu or run "
+            f"with jobs=1")
     ctx = dict(ctx or {})
     cell_ctx = cell_ctx or {}
     names = [c.name for c in cells]
@@ -323,6 +337,8 @@ def main(argv=None) -> int:
     ap.add_argument("--quiet", action="store_true",
                     help="suppress per-cell progress lines")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.spec import SpecError, load_sweep
     try:

@@ -111,6 +111,8 @@ def main(argv=None):
                     help="override global batch (0 = production 256)")
     ap.add_argument("--checkpoint", default=None)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.spec:
         # the spec file defines the experiment; a mesh-path flag alongside
@@ -139,11 +141,11 @@ def main(argv=None):
 
     from repro import configs
     from repro.launch import steps as steps_mod
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import make_mesh, make_production_mesh
 
     if args.mesh_shape:
         dd, mm = (int(x) for x in args.mesh_shape.split(","))
-        mesh = jax.make_mesh((dd, mm), ("data", "model"))
+        mesh = make_mesh((dd, mm), ("data", "model"))
     else:
         mesh = make_production_mesh()
     print(f"mesh: {dict(mesh.shape)}  devices: {len(jax.devices())}")

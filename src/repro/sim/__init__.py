@@ -29,6 +29,7 @@ from repro.sim.server import (           # noqa: F401
 )
 from repro.sim.engine import (           # noqa: F401
     EngineResult,
+    lower_rounds,
     run_rounds,
     run_to_objective,
 )

@@ -899,6 +899,17 @@ def _client_sharded(tree, m: int, mesh):
 # public entry point
 # ---------------------------------------------------------------------------
 
+def _chunk_args(sim: FedSim, H, masks, abandoned, noise) -> tuple:
+    """Arguments of the clocked chunk for the next ``len(abandoned)``
+    rounds of ``sim``: the one place ``run_rounds`` and ``lower_rounds``
+    build them."""
+    ridx0 = sim.round_idx
+    return (sim.state, H, sim._codec_key, jnp.asarray(masks),
+            jnp.asarray(abandoned),
+            jnp.arange(ridx0, ridx0 + len(abandoned), dtype=jnp.int32),
+            noise)
+
+
 def run_rounds(sim: FedSim, rounds: int, *, chunk: int | None = None,
                collect_w_tau: bool = False, mesh=None,
                event_table_capacity: int | None = None) -> EngineResult:
@@ -1009,9 +1020,7 @@ def run_rounds(sim: FedSim, rounds: int, *, chunk: int | None = None,
         else:
             noise = None
         (sim.state, H), ys = chunk_fn(
-            sim.state, H, sim._codec_key,
-            jnp.asarray(masks), jnp.asarray(abandoned),
-            jnp.arange(ridx0, ridx0 + C, dtype=jnp.int32), noise)
+            *_chunk_args(sim, H, masks, abandoned, noise))
         rm_stack = ys[0]
         if collect_w_tau:
             w_parts.append(np.asarray(jax.device_get(ys[1])))
@@ -1066,6 +1075,23 @@ def run_rounds(sim: FedSim, rounds: int, *, chunk: int | None = None,
         sim._H = H
     return EngineResult(
         out_metrics, np.concatenate(w_parts) if collect_w_tau else None)
+
+
+def lower_rounds(sim: FedSim, rounds: int):
+    """Lower, without running, the clocked chunk ``run_rounds`` compiles
+    for ``rounds`` rounds of ``sim`` -> ``jax.stages.Lowered``.
+
+    ``.compile()`` on the result gives the program's compile time and its
+    ``memory_analysis()`` before any device memory is spent on it. Clocked
+    policies without upload privacy (whose noise stack is host-drawn).
+    """
+    if sim.sim.policy not in _SCAN_POLICIES or sim._privacy_tx is not None:
+        raise ValueError("lower_rounds covers the clocked policies without "
+                         f"upload privacy; policy is {sim.sim.policy!r}")
+    H = sim._H if sim._ef else jnp.zeros((), jnp.float32)
+    return _chunk_fn(sim, False).lower(*_chunk_args(
+        sim, H, np.ones((rounds, sim.cfg.m), bool), np.zeros(rounds, bool),
+        None))
 
 
 def run_to_objective(sim: FedSim, objective_fn, target: float, *,

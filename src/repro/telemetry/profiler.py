@@ -5,14 +5,13 @@ fleet did; this module answers the other question -- where the WALL time of
 the scan engine actually goes (compile vs. dispatch vs. device compute).
 ``jax_profile(trace_dir)`` wraps a run in ``jax.profiler.start_trace`` /
 ``stop_trace``; the resulting TensorBoard/Perfetto trace lands under
-``trace_dir``. A falsy ``trace_dir`` makes it a no-op, and profiler
-start/stop failures degrade to a warning rather than killing the run (the
-profiler is diagnostics, never a dependency of results).
+``trace_dir``. A falsy ``trace_dir`` makes it a no-op. When a trace was
+requested, a failed profiler start or stop raises: a run that was asked
+for a trace and silently wrote none would pass for a traced one.
 """
 from __future__ import annotations
 
 import contextlib
-import warnings
 
 
 @contextlib.contextmanager
@@ -21,21 +20,9 @@ def jax_profile(trace_dir):
     if not trace_dir:
         yield
         return
-    started = False
-    try:
-        import jax
-        jax.profiler.start_trace(str(trace_dir))
-        started = True
-    except Exception as e:  # pragma: no cover - environment-dependent
-        warnings.warn(f"jax.profiler trace could not start: {e}",
-                      stacklevel=2)
+    import jax
+    jax.profiler.start_trace(str(trace_dir))
     try:
         yield
     finally:
-        if started:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception as e:  # pragma: no cover
-                warnings.warn(f"jax.profiler trace could not stop: {e}",
-                              stacklevel=2)
+        jax.profiler.stop_trace()

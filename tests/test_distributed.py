@@ -26,7 +26,8 @@ from repro.core.tasks import make_lm_loss
 from repro.models import registry
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 
 cfg = configs.get_reduced("smollm-135m")
 model = registry.get_model(cfg)

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 from repro.launch.steps import build_step, Skip
 from repro.launch.dryrun import collective_census, while_loop_info
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 
 # Use reduced configs via monkeypatching get_config so the small mesh can
 # hold them (full configs need the 256-chip mesh).

@@ -19,6 +19,7 @@ from repro.sim import (
     CodecConfig,
     FedSim,
     SimConfig,
+    lower_rounds,
     make_profiles,
     run_rounds,
     run_to_objective,
@@ -368,3 +369,20 @@ def test_bench_engine_quick_schema(tmp_path):
     assert a["speedup_rounds_per_sec"] >= 1.0
     assert a["engines"]["scan"]["host_syncs"] < \
         a["engines"]["eager"]["host_syncs"]
+
+
+def test_lower_rounds_is_the_program_run_rounds_compiles(task):
+    """lower_rounds lowers the cached chunk function run_rounds then runs,
+    and touches no state; the async policy and upload privacy are out of
+    its scope."""
+    from repro.sim import engine
+    sim = _build(task, "deadline", {"deadline": 0.002})
+    k0 = sim.state.k
+    lowered = lower_rounds(sim, 3)
+    assert lowered.compile().memory_analysis() is not None
+    assert int(sim.state.k) == int(k0) and sim.round_idx == 0
+    n_cached = len(engine._CHUNK_FN_CACHE)
+    run_rounds(sim, 3)
+    assert len(engine._CHUNK_FN_CACHE) == n_cached
+    with pytest.raises(ValueError, match="clocked"):
+        lower_rounds(_build(task, *POLICIES[-1]), 1)

@@ -41,6 +41,22 @@ def test_pallas_matches_ref_bitexact(m, n, bits, stochastic):
     assert qp.dtype == X.dtype
 
 
+@pytest.mark.parametrize("jit", [False, True])
+def test_u32_to_f32_matches_astype_bitexact(jit):
+    """The kernels' Mosaic-safe uint32 -> float32 conversion rounds exactly
+    like the direct cast: random words plus the rounding edges."""
+    from repro.kernels.common import u32_to_f32
+    edges = np.array([0, 1, 2**16 - 1, 2**16, 2**24 - 1, 2**24, 2**24 + 1,
+                      2**24 + 3, 2**31 - 1, 2**31, 2**31 + 1, 2**32 - 129,
+                      2**32 - 128, 2**32 - 127, 2**32 - 1], np.uint32)
+    u = jnp.concatenate([jax.random.bits(jax.random.PRNGKey(3), (1 << 20,),
+                                         dtype=jnp.uint32), edges])
+    conv = jax.jit(u32_to_f32) if jit else u32_to_f32
+    got = np.asarray(conv(u)).view(np.uint32)
+    want = np.asarray(u.astype(jnp.float32)).view(np.uint32)
+    assert np.array_equal(got, want)
+
+
 @pytest.mark.parametrize("bits", [2, 4, 8])
 def test_quantization_error_bounded(bits):
     """|q - x| <= delta (stochastic) resp. delta/2 (deterministic)."""

@@ -164,7 +164,9 @@ def test_execute_cells_end_to_end(tmp_path):
                                 cell_ctx={"nope": {}})
 
 
-def test_kill_resume_and_jobs_give_identical_merged(tmp_path):
+def test_kill_resume_and_jobs_give_identical_merged(tmp_path, monkeypatch):
+    # worker processes are allowed only with the CPU pinned
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     cells = _grid()
     # reference: uninterrupted --jobs 1 run
     a = tmp_path / "a"
@@ -232,6 +234,19 @@ def test_cli_exit_codes_and_resume(tmp_path):
     before = (out / "merged.json").read_bytes()
     assert sweep_run.main(argv) == sweep_run.EXIT_OK
     assert (out / "merged.json").read_bytes() == before
+
+
+@pytest.mark.parametrize("platforms", [None, "tpu", "cpu,tpu"])
+def test_parallel_jobs_refused_off_cpu(tmp_path, monkeypatch, platforms):
+    """An accelerator serves one process: jobs > 1 needs JAX_PLATFORMS=cpu,
+    and the refusal comes before any cell runs."""
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    with pytest.raises(ValueError, match="JAX_PLATFORMS=cpu"):
+        sweep_run.execute_cells(_grid(), out_dir=tmp_path, jobs=2)
+    assert not (tmp_path / "cells").exists()
 
 
 def test_cell_filename_is_safe_and_collision_free():

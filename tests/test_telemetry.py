@@ -389,3 +389,22 @@ def test_cli_spec_telemetry_override(tmp_path):
     assert rc == 0
     s = json.loads(out.read_text())
     assert s["telemetry"]["counters"]["rounds"] >= 1
+
+
+@pytest.mark.parametrize("fails", ["start_trace", "stop_trace"])
+def test_jax_profile_failure_raises(monkeypatch, tmp_path, fails):
+    """A requested trace that cannot start or stop fails the run instead
+    of warning; no trace dir means the profiler is never touched."""
+    from repro.telemetry import jax_profile
+
+    def boom(*a, **k):
+        raise RuntimeError(f"{fails} failed")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    monkeypatch.setattr(jax.profiler, fails, boom)
+    with pytest.raises(RuntimeError, match=fails):
+        with jax_profile(tmp_path / "trace"):
+            pass
+    with jax_profile(None):
+        pass
