@@ -86,6 +86,7 @@ def _gamma(cfg: BaselineConfig, k):
     return cfg.gamma_scale * cfg.d_i / jnp.sqrt(2.0 * cfg.k0 + tau)
 
 
+@jax.named_scope("aggregate")
 def _aggregate_selected_mean(Z, mask):
     """Eq. (34): mean over selected uploads."""
     cnt = jnp.maximum(jnp.sum(mask), 1).astype(jnp.float32)
@@ -97,6 +98,7 @@ def _aggregate_selected_mean(Z, mask):
     return tmap(agg, Z)
 
 
+@jax.named_scope("dp_noise")
 def _noisy_upload(k_noise, W_upd, g, mask, cfg: BaselineConfig, k):
     grad_l1 = jax.vmap(lambda gi: dp.sensitivity_surrogate(gi) / 2.0)(g)
     if cfg.eps_dp <= 0:
@@ -147,7 +149,8 @@ def sfedavg_round(state: BaselineState, batches: Batch, loss_fn: LossFn,
         g_last = grad_fn(w_final, b)
         return w_final, g_last
 
-    W_upd, g = jax.vmap(client)(state.W, batches)
+    with jax.named_scope("client_update"):
+        W_upd, g = jax.vmap(client)(state.W, batches)
     W_next = tree_where_client(mask, W_upd, state.W)
     Z_upd, snr, grad_l1 = _noisy_upload(k_noise, W_upd, g, mask, cfg, state.k)
     Z_next = tree_where_client(mask, Z_upd, state.Z)
@@ -194,7 +197,8 @@ def sfedprox_round(state: BaselineState, batches: Batch, loss_fn: LossFn,
         g_last = grad_fn(w_final, b)
         return w_final, g_last
 
-    W_upd, g = jax.vmap(client)(state.W, batches)
+    with jax.named_scope("client_update"):
+        W_upd, g = jax.vmap(client)(state.W, batches)
     W_next = tree_where_client(mask, W_upd, state.W)
     Z_upd, snr, grad_l1 = _noisy_upload(k_noise, W_upd, g, mask, cfg, state.k)
     Z_next = tree_where_client(mask, Z_upd, state.Z)

@@ -165,35 +165,41 @@ def fedepm_round(state: FedEPMState, batches: Batch, loss_fn: LossFn,
         mask = _select(k_sel, cfg, round_idx)
 
     # ---- server: aggregate uploads via ENS (19) and broadcast ----
-    w_new = ens_ops.ens_tree(state.Z, cfg.lam, cfg.eta, impl=cfg.ens_impl)
+    with jax.named_scope("ens"):
+        w_new = ens_ops.ens_tree(state.Z, cfg.lam, cfg.eta,
+                                 impl=cfg.ens_impl)
 
     # ---- clients: one gradient per round at the broadcast point (18) ----
-    grad_fn = jax.grad(loss_fn)
-    g = jax.vmap(lambda b: grad_fn(w_new, b))(batches)  # stacked (m, ...)
+    with jax.named_scope("client_grad"):
+        grad_fn = jax.grad(loss_fn)
+        g = jax.vmap(lambda b: grad_fn(w_new, b))(batches)  # stacked (m, ...)
 
     # ---- k0 inner prox iterations per client (20) ----
-    W_upd, mu_last = jax.vmap(
-        lambda wi, gi: _client_inner(wi, w_new, gi, state.k, cfg)
-    )(state.W, g)
+    with jax.named_scope("client_prox"):
+        W_upd, mu_last = jax.vmap(
+            lambda wi, gi: _client_inner(wi, w_new, gi, state.k, cfg)
+        )(state.W, g)
     W_next = tree_where_client(mask, W_upd, state.W)
 
     # ---- DP-noised upload (21)/(39) ----
-    grad_l1 = jax.vmap(lambda gi: dp.sensitivity_surrogate(gi) / 2.0)(g)
-    delta_hat = 2.0 * grad_l1
-    if cfg.sensitivity_clip > 0:
-        delta_hat = jnp.minimum(delta_hat, cfg.sensitivity_clip)
-    if cfg.eps_dp > 0:
-        scale = dp.fedepm_noise_scale(delta_hat, cfg.eps_dp, mu_last)  # (m,)
-        keys = jax.random.split(k_noise, cfg.m)
-        noise = jax.vmap(lambda kk, wi, s: dp.laplace_tree(kk, wi, s))(
-            keys, W_upd, scale)
-        Z_upd = tmap(jnp.add, W_upd, noise)
-        snr_i = jax.vmap(dp.snr_db10)(W_upd, noise)  # (m,)
-        snr = jnp.min(jnp.where(mask, snr_i, jnp.inf))
-    else:
-        scale = jnp.zeros((cfg.m,))
-        Z_upd = W_upd
-        snr = jnp.asarray(jnp.inf)
+    with jax.named_scope("dp_noise"):
+        grad_l1 = jax.vmap(lambda gi: dp.sensitivity_surrogate(gi) / 2.0)(g)
+        delta_hat = 2.0 * grad_l1
+        if cfg.sensitivity_clip > 0:
+            delta_hat = jnp.minimum(delta_hat, cfg.sensitivity_clip)
+        if cfg.eps_dp > 0:
+            scale = dp.fedepm_noise_scale(delta_hat, cfg.eps_dp,
+                                          mu_last)  # (m,)
+            keys = jax.random.split(k_noise, cfg.m)
+            noise = jax.vmap(lambda kk, wi, s: dp.laplace_tree(kk, wi, s))(
+                keys, W_upd, scale)
+            Z_upd = tmap(jnp.add, W_upd, noise)
+            snr_i = jax.vmap(dp.snr_db10)(W_upd, noise)  # (m,)
+            snr = jnp.min(jnp.where(mask, snr_i, jnp.inf))
+        else:
+            scale = jnp.zeros((cfg.m,))
+            Z_upd = W_upd
+            snr = jnp.asarray(jnp.inf)
     Z_next = tree_where_client(mask, Z_upd, state.Z)
 
     drift = tree_sq_norm(tmap(jnp.subtract, w_new, state.w_tau))

@@ -87,8 +87,9 @@ def _attn_full(x, p, cfg: ArchConfig, positions):
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
     mode = "bidirectional" if cfg.attention == "bidirectional" else "causal"
-    o = flash_attention(q, k, v, mode=mode, window=cfg.sliding_window,
-                        q_positions=positions, kv_positions=positions)
+    with jax.named_scope("attention"):
+        o = flash_attention(q, k, v, mode=mode, window=cfg.sliding_window,
+                            q_positions=positions, kv_positions=positions)
     return out_proj(o, p), k, v
 
 

@@ -119,6 +119,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import baselines, fedepm, participation
 from repro.core.treeutil import tmap, tree_where, tree_where_client
@@ -469,9 +470,11 @@ class _CandStream:
 
     def mask(self, n_fires: int) -> np.ndarray:
         while n_fires >= len(self._masks):
-            cands, self._key, self._k = self._fn(self._key, self._k)
+            with TraceAnnotation("repro.engine.candidates",
+                                 round=self._sim.round_idx):
+                cands, self._key, self._k = self._fn(self._key, self._k)
+                self._masks.extend(np.asarray(cands))
             self._sim.host_syncs += 1
-            self._masks.extend(np.asarray(cands))
         return self._masks[n_fires]
 
 
@@ -694,9 +697,11 @@ def _record_replay_chunk(sim: FedSim, C: int, collect_w_tau: bool,
     sim._exec = rec
     try:
         mets = []
-        for t in range(C):
-            rec.cur_step = t
-            mets.append(sim.step())
+        with TraceAnnotation("repro.engine.async_record",
+                             round=sim.round_idx):
+            for t in range(C):
+                rec.cur_step = t
+                mets.append(sim.step())
     finally:
         sim._exec = _EAGER_ASYNC_EXEC
 
@@ -781,33 +786,37 @@ def _record_replay_chunk(sim: FedSim, C: int, collect_w_tau: bool,
             # masked off, the values never land)
             like = sim._noise_row_like
             zero = tmap(lambda sd: jnp.zeros(sd.shape, sd.dtype), like)
-            flat = [draw_unit_noise(
-                jax.random.fold_in(sim._privacy_key, int(mserial[i, j])),
-                like, sim._privacy_tx) if mvalid[i, j] else zero
-                for i in range(n_pad) for j in range(m_pad)]
-            if flat:
-                merges_x["noise"] = tmap(
-                    lambda *ls: jnp.stack(ls).reshape(
-                        (n_pad, m_pad) + ls[0].shape), *flat)
-            else:
-                merges_x["noise"] = tmap(
-                    lambda sd: jnp.zeros((n_pad, m_pad) + sd.shape,
-                                         sd.dtype), like)
+            with TraceAnnotation("repro.engine.noise", round=sim.round_idx):
+                flat = [draw_unit_noise(
+                    jax.random.fold_in(sim._privacy_key,
+                                       int(mserial[i, j])),
+                    like, sim._privacy_tx) if mvalid[i, j] else zero
+                    for i in range(n_pad) for j in range(m_pad)]
+                if flat:
+                    merges_x["noise"] = tmap(
+                        lambda *ls: jnp.stack(ls).reshape(
+                            (n_pad, m_pad) + ls[0].shape), *flat)
+                else:
+                    merges_x["noise"] = tmap(
+                        lambda sd: jnp.zeros((n_pad, m_pad) + sd.shape,
+                                             sd.dtype), like)
         xs = {"fire_valid": jnp.asarray(fire_valid),
               "mask": jnp.asarray(mask), "agg": jnp.asarray(agg),
               "slot_src": jnp.asarray(slot_src), "step": jnp.asarray(step),
               "merges": merges_x}
-        state, H, tz, tw, ws, rms = fn(sim.state, H, table.z, table.w,
-                                       ws0, sim._codec_key, xs)
-        sim.state = state
-        if sim._ef:
-            sim._H = H
-        table.z, table.w = tz, tw
-        if last_fire >= 0:
-            sim.last_round_metrics = tmap(lambda y: y[last_fire], rms)
-        if collect_w_tau:
-            w_np = np.asarray(jax.device_get(ws))
-            sim.host_syncs += 1
+        with TraceAnnotation("repro.engine.async_replay",
+                             round=sim.round_idx):
+            state, H, tz, tw, ws, rms = fn(sim.state, H, table.z, table.w,
+                                           ws0, sim._codec_key, xs)
+            sim.state = state
+            if sim._ef:
+                sim._H = H
+            table.z, table.w = tz, tw
+            if last_fire >= 0:
+                sim.last_round_metrics = tmap(lambda y: y[last_fire], rms)
+            if collect_w_tau:
+                w_np = np.asarray(jax.device_get(ws))
+                sim.host_syncs += 1
 
     # in-flight table-backed contributions now reference the NEW table
     # trees (the old ones were donated into the chunk program)
@@ -972,13 +981,15 @@ def run_rounds(sim: FedSim, rounds: int, *, chunk: int | None = None,
     done = 0
     while done < rounds:
         C = min(chunk, rounds - done)
+        ridx0 = sim.round_idx
         # 1. arrivals: same host-RNG stream as C eager steps
-        arrivals = np.stack([
-            simclients.round_arrivals(
-                sim.profiles, sim._rng, sim._latency,
-                work_flops=sim._work, down_bytes=sim._down_bytes,
-                up_bytes=sim._up_bytes)
-            for _ in range(C)])
+        with TraceAnnotation("repro.engine.arrivals", round=ridx0):
+            arrivals = np.stack([
+                simclients.round_arrivals(
+                    sim.profiles, sim._rng, sim._latency,
+                    work_flops=sim._work, down_bytes=sim._down_bytes,
+                    up_bytes=sim._up_bytes)
+                for _ in range(C)])
         # 2./3. candidate-stream + policy replay to the abandoned fixpoint
         ewma0 = sim.deadlines.ewma.copy() \
             if sim.sim.policy == "adaptive" else None
@@ -990,86 +1001,90 @@ def run_rounds(sim: FedSim, rounds: int, *, chunk: int | None = None,
             if sim._faults is not None else None
         abandoned = np.zeros(C, bool)
         for _ in range(C + 1):
-            cands = np.asarray(cand_stream(
-                sim.state.key, sim.state.k, jnp.asarray(abandoned)))
+            with TraceAnnotation("repro.engine.candidates", round=ridx0):
+                cands = np.asarray(cand_stream(
+                    sim.state.key, sim.state.k, jnp.asarray(abandoned)))
             sim.host_syncs += 1
             if ewma0 is not None:
                 sim.deadlines.ewma = ewma0.copy()
             if fstate0 is not None:
                 sim._faults.state_restore(fstate0)
-            (masks, durs, ab_new, rec_ups, cands_eff, arrs_eff,
-             fouts) = _policy_stream_host(sim, cands, arrivals)
+            with TraceAnnotation("repro.engine.policy", round=ridx0):
+                (masks, durs, ab_new, rec_ups, cands_eff, arrs_eff,
+                 fouts) = _policy_stream_host(sim, cands, arrivals)
             if np.array_equal(ab_new, abandoned):
                 break
             abandoned = ab_new
         else:  # pragma: no cover - the prefix argument guarantees progress
             raise RuntimeError("abandoned-round fixpoint did not converge")
         # 4. one donated scan over the chunk
-        ridx0 = sim.round_idx
         if sim._privacy_tx is not None:
             # per-round unit noise, drawn host-side through the SAME
             # standalone program the eager step uses (one draw per round,
             # privacy stream folded on the round index), stacked as xs --
             # see transport.draw_unit_noise for why the draws must enter
             # the chunk as data rather than be computed in-body
-            draws = [draw_unit_noise(
-                jax.random.fold_in(sim._privacy_key, r),
-                sim.state.Z, sim._privacy_tx)
-                for r in range(ridx0, ridx0 + C)]
-            noise = tmap(lambda *ls: jnp.stack(ls), *draws)
+            with TraceAnnotation("repro.engine.noise", round=ridx0):
+                draws = [draw_unit_noise(
+                    jax.random.fold_in(sim._privacy_key, r),
+                    sim.state.Z, sim._privacy_tx)
+                    for r in range(ridx0, ridx0 + C)]
+                noise = tmap(lambda *ls: jnp.stack(ls), *draws)
         else:
             noise = None
-        (sim.state, H), ys = chunk_fn(
-            *_chunk_args(sim, H, masks, abandoned, noise))
-        rm_stack = ys[0]
-        if collect_w_tau:
-            w_parts.append(np.asarray(jax.device_get(ys[1])))
-            sim.host_syncs += 1
+        with TraceAnnotation("repro.engine.dispatch", round=ridx0):
+            (sim.state, H), ys = chunk_fn(
+                *_chunk_args(sim, H, masks, abandoned, noise))
+            rm_stack = ys[0]
+            if collect_w_tau:
+                w_parts.append(np.asarray(jax.device_get(ys[1])))
+                sim.host_syncs += 1
 
         # host bookkeeping, identical to C eager steps
-        live = np.flatnonzero(~abandoned)
-        if live.size:
-            sim.last_round_metrics = tmap(
-                lambda y: y[int(live[-1])], rm_stack)
-        for t in range(C):
-            dur = float(durs[t])
-            # the scan path reconstructs the SAME event stream the eager
-            # driver emits: same helper, same already-computed host arrays
-            if sim.telemetry.enabled:
-                emit_clocked_round_events(
-                    sim.telemetry, policy=sim.sim.policy,
-                    round_idx=sim.round_idx, t0=sim.t,
-                    candidates=cands_eff[t], arrivals=arrs_eff[t],
-                    mask=masks[t], dur=dur, rec_up=rec_ups[t],
-                    abandoned=bool(abandoned[t]), codec=sim.sim.codec,
-                    up_bytes=sim._up_bytes, faults=fouts[t])
-            apply_clocked_privacy(
-                sim._privacy, sim.telemetry, round_idx=sim.round_idx,
-                t_end=sim.t + dur, mask=masks[t], rec_up=rec_ups[t],
-                faults=fouts[t])
-            if fouts[t] is None:
-                brec = sim.ledger.record_round(
-                    down_mask=cands_eff[t], up_mask=rec_ups[t],
-                    down_bytes=sim._down_bytes, up_bytes=sim._up_bytes,
-                    ts=sim.t + dur, round_idx=sim.round_idx)
-            else:
-                # same count-path billing as the eager step: delivered
-                # uploads + failed attempts + discarded duplicates
-                brec = sim.ledger.record_counts(
-                    down_counts=cands_eff[t].astype(np.int64),
-                    up_counts=rec_ups[t].astype(np.int64)
-                    + fouts[t].extra_up,
-                    down_bytes=sim._down_bytes, up_bytes=sim._up_bytes,
-                    ts=sim.t + dur, round_idx=sim.round_idx)
-            sim.t += dur
-            m = make_sim_metrics(
-                round_idx=sim.round_idx, t_round=dur, t_total=sim.t,
-                n_contacted=int(cands_eff[t].sum()),
-                n_aggregated=int(masks[t].sum()), brec=brec,
-                abandoned=bool(abandoned[t]))
-            sim.metrics.append(m)
-            out_metrics.append(m)
-            sim.round_idx += 1
+        with TraceAnnotation("repro.engine.bookkeeping", round=ridx0):
+            live = np.flatnonzero(~abandoned)
+            if live.size:
+                sim.last_round_metrics = tmap(
+                    lambda y: y[int(live[-1])], rm_stack)
+            for t in range(C):
+                dur = float(durs[t])
+                # the scan path reconstructs the SAME event stream the eager
+                # step emits: same helper, same already-computed host arrays
+                if sim.telemetry.enabled:
+                    emit_clocked_round_events(
+                        sim.telemetry, policy=sim.sim.policy,
+                        round_idx=sim.round_idx, t0=sim.t,
+                        candidates=cands_eff[t], arrivals=arrs_eff[t],
+                        mask=masks[t], dur=dur, rec_up=rec_ups[t],
+                        abandoned=bool(abandoned[t]), codec=sim.sim.codec,
+                        up_bytes=sim._up_bytes, faults=fouts[t])
+                apply_clocked_privacy(
+                    sim._privacy, sim.telemetry, round_idx=sim.round_idx,
+                    t_end=sim.t + dur, mask=masks[t], rec_up=rec_ups[t],
+                    faults=fouts[t])
+                if fouts[t] is None:
+                    brec = sim.ledger.record_round(
+                        down_mask=cands_eff[t], up_mask=rec_ups[t],
+                        down_bytes=sim._down_bytes, up_bytes=sim._up_bytes,
+                        ts=sim.t + dur, round_idx=sim.round_idx)
+                else:
+                    # same count-path billing as the eager step: delivered
+                    # uploads + failed attempts + discarded duplicates
+                    brec = sim.ledger.record_counts(
+                        down_counts=cands_eff[t].astype(np.int64),
+                        up_counts=rec_ups[t].astype(np.int64)
+                        + fouts[t].extra_up,
+                        down_bytes=sim._down_bytes, up_bytes=sim._up_bytes,
+                        ts=sim.t + dur, round_idx=sim.round_idx)
+                sim.t += dur
+                m = make_sim_metrics(
+                    round_idx=sim.round_idx, t_round=dur, t_total=sim.t,
+                    n_contacted=int(cands_eff[t].sum()),
+                    n_aggregated=int(masks[t].sum()), brec=brec,
+                    abandoned=bool(abandoned[t]))
+                sim.metrics.append(m)
+                out_metrics.append(m)
+                sim.round_idx += 1
         done += C
     if sim._ef:
         sim._H = H
@@ -1077,19 +1092,23 @@ def run_rounds(sim: FedSim, rounds: int, *, chunk: int | None = None,
         out_metrics, np.concatenate(w_parts) if collect_w_tau else None)
 
 
-def lower_rounds(sim: FedSim, rounds: int):
+def lower_rounds(sim: FedSim, rounds: int, *,
+                 collect_w_tau: bool = False):
     """Lower, without running, the clocked chunk ``run_rounds`` compiles
-    for ``rounds`` rounds of ``sim`` -> ``jax.stages.Lowered``.
+    for ``rounds`` rounds of ``sim`` (with ``collect_w_tau`` as
+    ``run_rounds`` is given it) -> ``jax.stages.Lowered``.
 
     ``.compile()`` on the result gives the program's compile time and its
-    ``memory_analysis()`` before any device memory is spent on it. Clocked
-    policies without upload privacy (whose noise stack is host-drawn).
+    ``memory_analysis()`` before any device memory is spent on it, and its
+    ``as_text()`` the op metadata a profiler trace's op names map to.
+    Clocked policies without upload privacy (whose noise stack is
+    host-drawn).
     """
     if sim.sim.policy not in _SCAN_POLICIES or sim._privacy_tx is not None:
         raise ValueError("lower_rounds covers the clocked policies without "
                          f"upload privacy; policy is {sim.sim.policy!r}")
     H = sim._H if sim._ef else jnp.zeros((), jnp.float32)
-    return _chunk_fn(sim, False).lower(*_chunk_args(
+    return _chunk_fn(sim, collect_w_tau).lower(*_chunk_args(
         sim, H, np.ones((rounds, sim.cfg.m), bool), np.zeros(rounds, bool),
         None))
 

@@ -138,6 +138,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import baselines, fedepm, participation
 from repro.core.treeutil import tmap, tree_size, tree_where_client
@@ -362,6 +363,7 @@ class _Contribution:
     #                    carries no batch refs and owns no table slot)
 
 
+@jax.named_scope("merge")
 def merge_contribution(Z, W, H, z_batch, w_batch, batch_row, idx, gamma,
                        key, noise, *, codec: CodecConfig | None, ef: bool,
                        privacy: PrivacyConfig | None = None):
@@ -917,8 +919,12 @@ class FedSim:
     # -- one simulated round ------------------------------------------------
 
     def step(self) -> SimMetrics:
-        if self.sim.policy == "async":
-            return self._step_async()
+        with TraceAnnotation("repro.sim.step", round=self.round_idx):
+            if self.sim.policy == "async":
+                return self._step_async()
+            return self._step_clocked()
+
+    def _step_clocked(self) -> SimMetrics:
         candidates = np.asarray(self._candidates(self.state))
         self.host_syncs += 1
         arrivals = simclients.round_arrivals(

@@ -481,6 +481,7 @@ def _codec_group(z_leaves, fb_leaves, key, codec: CodecConfig,
     return _unstack_rows(out_rows, gp, m)
 
 
+@jax.named_scope("upload_codec")
 def codec_roundtrip(tree_z, tree_fallback, key: jax.Array,
                     codec: CodecConfig | None):
     """Encode + decode every client's upload; stacked (m, ...) pytrees.
@@ -556,6 +557,7 @@ def _ef_group(z_leaves, h_leaves, key, codec: CodecConfig, gp: _GroupPlan):
     return _unstack_rows(out_rows, gp, m)
 
 
+@jax.named_scope("upload_ef")
 def ef_roundtrip(tree_z, tree_h, key: jax.Array, codec: CodecConfig | None):
     """Error-feedback encode + decode; stacked (m, ...) pytrees.
 
@@ -750,6 +752,7 @@ def _fused_private(leaves, treedef, key, noise, codec: CodecConfig,
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
+@jax.named_scope("upload_privacy")
 def private_roundtrip(tree_z, tree_fallback, key: jax.Array,
                       noise, codec: CodecConfig | None, privacy):
     """Clip + DP-noise + codec round-trip; stacked (m, ...) pytrees.
@@ -785,6 +788,7 @@ def private_roundtrip(tree_z, tree_fallback, key: jax.Array,
     return codec_roundtrip(noisy, tree_fallback, key, codec)
 
 
+@jax.named_scope("upload_privacy")
 def private_ef_roundtrip(tree_z, tree_h, key: jax.Array, noise,
                          codec: CodecConfig | None, privacy):
     """Error-feedback variant: EF compresses the NOISY upload's residual.

@@ -32,6 +32,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import fedepm
 from repro.sim import FedSim, SimConfig, run_rounds
@@ -197,9 +198,18 @@ class RunHandle:
         if not self.data.supports_accuracy:
             return None
         from repro.core.tasks import accuracy_logistic
+        self.sim.host_syncs += 1
         return float(accuracy_logistic(
             self.sim.state.w_tau, jnp.asarray(self.data.aux["X"]),
             jnp.asarray(self.data.aux["y"])))
+
+    def _read_objective(self, w, round_idx: int, *,
+                        span: str = "repro.run.objective") -> float:
+        """f(w) on the host: one blocking device-to-host transfer, counted
+        in ``sim.host_syncs`` and named in a profiler trace."""
+        self.sim.host_syncs += 1
+        with TraceAnnotation(span, round=round_idx):
+            return float(self._fobj(w))
 
     # -- the execution loop --------------------------------------------------
 
@@ -215,6 +225,7 @@ class RunHandle:
         if not any(not mm.abandoned for mm in metrics):
             return False
         from repro.configs.paper_logreg import termination_reached
+        self.sim.host_syncs += 1
         return termination_reached(
             f_hist, float(self._gsq(w)), self.data.n_features)
 
@@ -250,7 +261,8 @@ class RunHandle:
                 for _ in range(eng.rounds):
                     met = sim.step()
                     rounds_run += 1
-                    f_hist.append(float(self._fobj(sim.state.w_tau)))
+                    f_hist.append(self._read_objective(
+                        sim.state.w_tau, sim.round_idx - 1))
                     if report is not None:
                         report(met, f_hist[-1])
                     if self._terminated(f_hist, w=sim.state.w_tau,
@@ -270,6 +282,7 @@ class RunHandle:
                     # eager loop would have -- the stopping round is
                     # decided from the chunk's per-round broadcast stream
                     snap = sim.snapshot() if check else None
+                    r0 = sim.round_idx
                     res = run_rounds(sim, todo, collect_w_tau=collect,
                                      mesh=eng.mesh,
                                      event_table_capacity=(
@@ -278,7 +291,7 @@ class RunHandle:
                         for i, (met, w) in enumerate(
                                 zip(res.metrics, res.w_tau)):
                             w = jnp.asarray(w)
-                            f_hist.append(float(self._fobj(w)))
+                            f_hist.append(self._read_objective(w, r0 + i))
                             if report is not None:
                                 report(met, f_hist[-1])
                             if check and self._terminated(
@@ -320,8 +333,9 @@ class RunHandle:
 
     def _summary(self, f_hist: list, rounds_run: int) -> dict:
         sim, spec = self.sim, self.spec
-        f_final = f_hist[-1] if f_hist \
-            else float(self._fobj(sim.state.w_tau))
+        f_final = f_hist[-1] if f_hist else self._read_objective(
+            sim.state.w_tau, sim.round_idx - 1,
+            span="repro.run.final_objective")
         summary = {
             "spec_name": spec.name,
             "alg": spec.algorithm.name, "policy": spec.policy.name,
