@@ -18,9 +18,10 @@ a grid of sims compiles each program once, not once per cell.
 ``RunHandle.run`` owns the execution loop both CLIs and the benchmarks
 reuse: the eager per-round path and the fused scan-chunk path (identical
 trajectories, docs/perf.md), per-round objective tracking where the
-broadcast point is a flat vector (the logreg task; LM pytrees are
-evaluated at chunk boundaries instead), and the paper's termination rule
-under ``engine.terminate``.
+broadcast point is a flat vector (the logreg task; a scan chunk's values
+come back in one batched read; LM pytrees are evaluated at chunk
+boundaries instead), and the paper's termination rule under
+``engine.terminate``.
 """
 from __future__ import annotations
 
@@ -165,19 +166,28 @@ class RunHandle:
     def __post_init__(self):
         loss, batches = self.data.loss_fn, self.data.batches
         key = (loss, id(batches))
-        # cap matches _TASK_CACHE's intent (2 entries per task): these
+        # cap matches _TASK_CACHE's intent (3 entries per task): these
         # closures pin the task's device batches, so a larger bound would
         # keep evicted tasks' datasets alive behind the task memo's back
         self._fobj = fifo_cache_get(
             _OBJ_CACHE, ("fobj", *key),
             lambda: jax.jit(
                 lambda w: fedepm.global_objective(loss, w, batches)),
-            cap=16)
+            cap=24)
+        # f at every row of a (rounds, d) broadcast stream. lax.map, not
+        # vmap: each row runs _fobj's own matvec, so the values are _fobj's
+        # bit for bit (vmap turns the matvecs into one matmul, which the
+        # TPU may round through bfloat16 at default precision)
+        self._fobjs = fifo_cache_get(
+            _OBJ_CACHE, ("fobjs", *key),
+            lambda: jax.jit(lambda ws: jax.lax.map(
+                lambda w: fedepm.global_objective(loss, w, batches), ws)),
+            cap=24)
         self._gsq = fifo_cache_get(
             _OBJ_CACHE, ("gsq", *key),
             lambda: jax.jit(
                 lambda w: fedepm.global_grad_sq_norm(loss, w, batches)),
-            cap=16)
+            cap=24)
         # per-round broadcast points can be stacked/tracked only when the
         # parameter pytree is one flat vector (the logreg task); LM pytrees
         # are evaluated at chunk boundaries instead
@@ -206,10 +216,20 @@ class RunHandle:
     def _read_objective(self, w, round_idx: int, *,
                         span: str = "repro.run.objective") -> float:
         """f(w) on the host: one blocking device-to-host transfer, counted
-        in ``sim.host_syncs`` and named in a profiler trace."""
+        in ``sim.host_syncs`` and named in a profiler trace. The eager
+        engine reads once a round, the summary once at the end."""
         self.sim.host_syncs += 1
         with TraceAnnotation(span, round=round_idx):
             return float(self._fobj(w))
+
+    def _read_objectives(self, ws: np.ndarray, round0: int) -> list[float]:
+        """f at each row of a scan chunk's (rounds, d) broadcast stream:
+        one upload, one program and one blocking transfer for the chunk,
+        counted once in ``sim.host_syncs``; the span's ``round`` is the
+        chunk's first round."""
+        self.sim.host_syncs += 1
+        with TraceAnnotation("repro.run.objective", round=round0):
+            return np.asarray(self._fobjs(jnp.asarray(ws))).tolist()
 
     # -- the execution loop --------------------------------------------------
 
@@ -243,6 +263,11 @@ class RunHandle:
         snapshot + series, repro.telemetry.sinks.telemetry_summary) and the
         configured sinks are written at run end; a telemetry-off summary
         is byte-identical to previous releases.
+
+        The eager engine reads f once a round. The scan engine reads a
+        chunk's per-round values in one batched program and one transfer,
+        then reports them, tests termination and rolls back round by
+        round, as the eager loop does.
         """
         eng = self.spec.engine
         entry = registry.ENGINES[eng.name]
@@ -288,12 +313,12 @@ class RunHandle:
                                      event_table_capacity=(
                                          eng.event_table_capacity))
                     if collect:
-                        for i, (met, w) in enumerate(
-                                zip(res.metrics, res.w_tau)):
-                            w = jnp.asarray(w)
-                            f_hist.append(self._read_objective(w, r0 + i))
+                        fs = self._read_objectives(res.w_tau, r0)
+                        for i, (met, f, w) in enumerate(
+                                zip(res.metrics, fs, res.w_tau)):
+                            f_hist.append(f)
                             if report is not None:
-                                report(met, f_hist[-1])
+                                report(met, f)
                             if check and self._terminated(
                                     f_hist, w=w,
                                     metrics=sim.metrics[:rounds_run + i

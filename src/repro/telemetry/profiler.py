@@ -42,7 +42,8 @@ DEVICE_SCOPES = (
 )
 
 HOST_SPANS = (
-    "repro.run.objective",        # spec/build.py: one per-round f(w) read
+    "repro.run.objective",        # spec/build.py: f(w) reads, one a
+                                  # round (eager), one a chunk (scan)
     "repro.run.final_objective",  # spec/build.py: summary's f(w) read
     "repro.engine.arrivals",      # sim/engine.py: a chunk's arrival draws
     "repro.engine.candidates",    # one candidate-stream pass + readback

@@ -1,14 +1,19 @@
 """The program's names in a profiler trace (repro.telemetry.profiler):
 device stages as ``jax.named_scope`` in the compiled chunk's op metadata,
-host phases as ``TraceAnnotation`` spans, and ``FedSim.host_syncs``
-counting every device-to-host read of ``RunHandle.run``."""
+host phases as ``TraceAnnotation`` spans, ``FedSim.host_syncs``
+counting every device-to-host read of ``RunHandle.run``, and the scan
+path's one batched objective read a chunk."""
 import pathlib
 import re
+import sys
 
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from repro.sim import lower_rounds
+from repro.sim import lower_rounds, run_rounds
 from repro.spec import ExperimentSpec
+from repro.spec.build import RunHandle
 from repro.telemetry.profiler import DEVICE_SCOPES, HOST_SPANS
 
 SRC = pathlib.Path(__file__).resolve().parents[1] / "src" / "repro"
@@ -85,20 +90,68 @@ def test_the_upload_chain_names_its_round_trips():
 
 @pytest.mark.parametrize("engine", ["eager", "scan"])
 def test_host_syncs_count_every_read_of_a_run(engine):
-    """Logreg, R rounds: each eager step reads its candidates and its
-    policy mask; each scan chunk reads its candidate stream (one pass
-    without abandoned rounds) and its broadcast stream; both read the
-    objective once a round and the accuracy once."""
+    """Logreg, R rounds: each eager step reads its candidates, its policy
+    mask and the round's objective; each scan chunk reads its candidate
+    stream (one pass without abandoned rounds), its broadcast stream and
+    its rounds' objectives (one batched read); both read the accuracy
+    once."""
     rounds, chunk = 6, 3
     h = _spec(engine, rounds,
               chunk=chunk if engine == "scan" else None).build()
     before = h.sim.host_syncs
     summary = h.run()
     assert summary["abandoned_rounds"] == 0
-    per_round = 2 if engine == "eager" else 0
-    per_chunk = 0 if engine == "eager" else 2
+    per_round = 3 if engine == "eager" else 0
+    per_chunk = 0 if engine == "eager" else 3
     assert h.sim.host_syncs - before == (
-        rounds * (per_round + 1) + (rounds // chunk) * per_chunk + 1)
+        rounds * per_round + (rounds // chunk) * per_chunk + 1)
+
+
+ASYNC = {"name": "async", "buffer_size": 3, "max_concurrency": 4}
+
+
+@pytest.mark.parametrize("policy,rounds,chunk", [
+    ({"name": "sync"}, 6, 3),
+    (ASYNC, 6, 3),
+    ({"name": "sync"}, 7, 3),
+], ids=["sync", "async", "partial-last-chunk"])
+def test_scan_objectives_are_the_objective_at_each_broadcast_point(
+        monkeypatch, policy, rounds, chunk):
+    """The scan path's batched read reports, round by round, exactly what
+    ``handle.objective`` reads at that round's broadcast point, and the
+    summary's f_final is the last of them."""
+    streams = []
+
+    def recording_run_rounds(*args, **kw):
+        res = run_rounds(*args, **kw)
+        streams.append(res.w_tau)
+        return res
+
+    monkeypatch.setattr(sys.modules[RunHandle.__module__], "run_rounds",
+                        recording_run_rounds)
+    h = _spec("scan", rounds, chunk=chunk, policy=policy).build()
+    fs = []
+    summary = h.run(report=lambda met, f: fs.append(f))
+    ws = np.concatenate(streams)
+    assert len(streams) == -(-rounds // chunk) and ws.shape[0] == rounds
+    want = [float(h.objective(jnp.asarray(w))) for w in ws]
+    assert all(type(f) is float for f in fs)
+    assert fs == want
+    assert summary["f_final"] == want[-1] / h.spec.task.m
+
+
+def test_a_second_handle_reuses_the_batched_objective():
+    """A new RunHandle over the same task (as a timed window builds one)
+    takes the compiled batched objective from the cache and compiles it
+    for no new shape."""
+    spec = _spec("scan", 4, chunk=2)
+    h = spec.build()
+    h.run()
+    again = RunHandle(spec=spec, sim=h.sim, data=h.data)
+    assert again._fobjs is h._fobjs
+    compiled = h._fobjs._cache_size()
+    again.run()
+    assert h._fobjs._cache_size() == compiled
 
 
 def test_host_syncs_count_the_lm_summary_read():
