@@ -95,6 +95,12 @@ def drop_program_caches() -> None:
     gc.collect()
 
 
+def mesh_chips(cfg: dict) -> int:
+    """The chips a configuration's client axis spans: its ``engine.mesh``,
+    or 1 where it sets none. A cell's ``chips`` has to be this."""
+    return int(cfg["spec"].get("engine", {}).get("mesh") or 1)
+
+
 def spec_dict(cfg: dict, mix: dict, seed: int, rounds: int) -> dict:
     """The ExperimentSpec of one cell: the configuration's sections
     (task, algorithm, engine chunk) and the mix's (fleet, policy, ...)."""
@@ -262,14 +268,17 @@ def run_window(cfg: dict, mix: dict, *, seed: int, seconds: float,
     return out
 
 
-def check(cfg: dict, mix: dict, seed: int, prog: dict) -> tuple[bool, dict]:
-    """Run the mix's reference over the first chunk; -> (correct, checks)."""
+def check(cfg: dict, mix: dict, seed: int, prog: dict,
+          chips: int = 1) -> tuple[bool, dict]:
+    """Run the mix's reference over the first chunk on the cell's
+    ``chips``; -> (correct, checks)."""
     import reference
     task = config_module(cfg["_file"])
     chunk = cfg["spec"]["engine"]["chunk"]
     spec = spec_dict(cfg, mix, seed, chunk)
     t = time.perf_counter()
-    ref = mix_reference(mix).run_reference(task, cfg, spec, seed, chunk)
+    ref = mix_reference(mix).run_reference(task, cfg, spec, seed, chunk,
+                                           chips=chips)
     vals = reference.compare(prog, ref)
     say(phase="check", s=time.perf_counter() - t)
     limits = cfg["limits"]

@@ -16,6 +16,15 @@ def idle_pct(ctx: dict):
     return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
 
 
+def collective_pct(ctx: dict):
+    """Share of the chips' busy time in which a collective ran: a union
+    within a union, so at most 100; None where none ran."""
+    tr = ctx["trace"]
+    if not tr or not tr["collective_s"] or tr["busy_s"] <= 0:
+        return None
+    return 100.0 * tr["collective_s"] / tr["busy_s"]
+
+
 def mfu(ctx: dict):
     tr = ctx["trace"]
     if not tr or not ctx["flops_per_round"] or tr["window_s"] <= 0:

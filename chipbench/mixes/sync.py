@@ -26,9 +26,12 @@ def round_host(cand: np.ndarray, arr: np.ndarray):
 
 
 def run_reference(task, cfg: dict, spec: dict, seed: int, rounds: int, *,
-                  lower: bool = False, fault: str | None = None) -> dict:
-    """Follow ``rounds`` sync rounds from the seed; -> the readings."""
-    st = reference.Start(task, cfg, spec, seed, lower=lower, fault=fault)
+                  lower: bool = False, fault: str | None = None,
+                  chips: int = 1) -> dict:
+    """Follow ``rounds`` sync rounds from the seed on ``chips`` chips;
+    -> the readings."""
+    st = reference.Start(task, cfg, spec, seed, lower=lower, fault=fault,
+                         chips=chips)
     cst = st.cst
     down_b = up_b = 4.0 * st.n_params
     work = reference.client_work_flops(cst.k0, st.n_params, st.d_local)

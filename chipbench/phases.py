@@ -32,8 +32,8 @@ JSON line:
   events on one clock.
 
 With these it prints the window's ``rounds``, ``rounds_per_s``,
-``host_syncs`` and the accepted reduction's ``busy_s`` and
-``window_s``. It reports no metric of ``BENCHMARK.json`` and checks
+``host_syncs`` and the accepted reduction's ``busy_s``, ``collective_s``
+and ``window_s``. It reports no metric of ``BENCHMARK.json`` and checks
 nothing against the reference. It needs an accelerator, as ``run.py``
 does, and exits 1 without one.
 """
@@ -329,6 +329,7 @@ def main(argv=None) -> int:
            "host_syncs": ev["host_syncs"], "module": ev["module"],
            "hlo_ops": len(ev["hlo_paths"]),
            "busy_s": tr.get("busy_s"), "trace_window_s": tr.get("window_s"),
+           "collective_s": tr.get("collective_s"),
            **(reduce_phases(ev["host"], ev["device"], DEVICE_SCOPES) or {}),
            "dispatch_lead_ms": dispatch_leads(ev["host"], device5,
                                               ev["module"])}

@@ -23,6 +23,13 @@ initial parameters, per-client loss) comes from the configuration's own
 reference module beside its JSON file. ``lower=True`` runs the same rounds
 in the task's next lower precision: that is the control that the
 comparison must reject.
+
+A configuration whose ``engine.mesh`` is n > 1 runs on n chips, and so
+does its reference: a 1-D mesh of its own over the first n devices holds
+every client's iterate and upload on the chip of its client, m/n clients a
+chip. There a round takes its chip's clients one after another, and the
+aggregate of each leaf is found on coordinate blocks that an all-to-all
+brings together from every chip, then gathered whole on every chip.
 """
 from __future__ import annotations
 
@@ -37,6 +44,7 @@ import numpy as np
 tmap = jax.tree_util.tree_map
 
 NOMINAL_FLOPS = 1e9          # simulated seconds per flop at speed 1
+CLIENT_AXIS = "clients"
 LATENCY_MODELS = ("deterministic", "lognormal", "pareto")
 
 
@@ -191,14 +199,12 @@ def prox_steps(wi, w_new, gi, k_start, cst: FedEPMConstants):
     return w, mus[-1]
 
 
-def make_round(loss, cst: FedEPMConstants):
-    """-> jitted ``round(W, Z, k, mask, batches, key) -> (w_new, W, Z,
-    grad_l1, per-leaf squared gradient norms)``. W and Z are donated; ``key``
-    is the round's noise key (the third of its 3-way split).
-
-    Clients are taken one after another (``lax.map``), so the largest
-    model's gradients never sit on the device all at once.
-    """
+def make_clients(loss, cst: FedEPMConstants):
+    """-> ``clients(W, Z, w_new, k, mask, batches, keys) -> (W, Z, grad_l1,
+    per-client per-leaf squared gradient norms)``: the round of every
+    client of the stack from the broadcast point ``w_new``, one after
+    another (``lax.map``), so the largest model's gradients never sit on
+    the device all at once. Clients outside ``mask`` keep W and Z."""
     grad = jax.grad(loss)
     noisy = cst.eps_dp > 0
 
@@ -217,10 +223,7 @@ def make_round(loss, cst: FedEPMConstants):
                        g)
         return w_upd, (z_upd if noisy else None), gl1, leaf_sq
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def round_(W, Z, k, mask, batches, key):
-        w_new = tmap(lambda z: ens(z, cst.lam, cst.eta), Z)
-        keys = jax.random.split(key, cst.m)
+    def clients(W, Z, w_new, k, mask, batches, keys):
         W_upd, Z_upd, gl1, leaf_sq = jax.lax.map(
             lambda a: one_client(a[0], w_new, a[1], k, a[2]),
             (W, batches, keys))
@@ -230,10 +233,109 @@ def make_round(loss, cst: FedEPMConstants):
         def sel(new, old):
             return jnp.where(mask.reshape((-1,) + (1,) * (new.ndim - 1)),
                              new, old)
-        return (w_new, tmap(sel, W_upd, W), tmap(sel, Z_upd, Z), gl1,
-                tmap(jnp.sum, leaf_sq))
+        return tmap(sel, W_upd, W), tmap(sel, Z_upd, Z), gl1, leaf_sq
+
+    return clients
+
+
+def make_round(loss, cst: FedEPMConstants):
+    """-> jitted ``round(W, Z, k, mask, batches, key) -> (w_new, W, Z,
+    grad_l1, per-leaf squared gradient norms)``. W and Z are donated; ``key``
+    is the round's noise key (the third of its 3-way split).
+    """
+    clients = make_clients(loss, cst)
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def round_(W, Z, k, mask, batches, key):
+        w_new = tmap(lambda z: ens(z, cst.lam, cst.eta), Z)
+        keys = jax.random.split(key, cst.m)
+        W, Z, gl1, leaf_sq = clients(W, Z, w_new, k, mask, batches, keys)
+        return w_new, W, Z, gl1, tmap(jnp.sum, leaf_sq)
 
     return round_
+
+
+def client_mesh(n: int):
+    """A 1-D mesh over the first ``n`` devices, its one axis the clients'."""
+    devs = jax.devices()
+    if len(devs) < n:
+        raise ValueError(f"the reference spans {n} chips; JAX found "
+                         f"{len(devs)}")
+    return jax.sharding.Mesh(np.array(devs[:n]), (CLIENT_AXIS,))
+
+
+def make_sharded_round(loss, cst: FedEPMConstants, mesh, *,
+                       exchange: bool = True):
+    """``make_round`` with the client axis over ``mesh``: the same
+    signature and results, W, Z, the mask, the batches and ``grad_l1``
+    split m/n clients a chip.
+
+    Each chip takes its own clients one after another. The aggregate is
+    found leaf by leaf: an all-to-all turns the chip's (m/n, coords) rows
+    into all m clients' rows of its coords/n block (the leaf flattened and
+    padded to a multiple of n), the chip solves ENS there, and an
+    all-gather makes the whole aggregate on every chip. So a chip holds
+    its clients' W, Z and updates, one client's gradient and one leaf's
+    (2m+1, coords/n) ENS block, never all m clients of a leaf.
+
+    ``exchange=False`` is a fault: the all-to-all and the all-gather are
+    left out, every chip aggregates its own clients and steps them from
+    that, and the broadcast point returned is chip 0's.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    n = mesh.shape[CLIENT_AXIS]
+    clients = make_clients(loss, cst)
+
+    def aggregate(z):
+        if not exchange:
+            return ens(z, cst.lam, cst.eta)
+        rows = z.reshape(z.shape[0], -1)
+        size = rows.shape[1]
+        rows = jnp.pad(rows, ((0, 0), (0, -size % n)))
+        block = jax.lax.all_to_all(rows, CLIENT_AXIS, 1, 0, tiled=True)
+        w = jax.lax.all_gather(ens(block, cst.lam, cst.eta), CLIENT_AXIS,
+                               tiled=True)
+        return w[:size].reshape(z.shape[1:])
+
+    def local(W, Z, mask, batches, keys, k):
+        w_new = tmap(aggregate, Z)
+        W, Z, gl1, leaf_sq = clients(W, Z, w_new, k, mask, batches, keys)
+        if not exchange:
+            w_new = tmap(lambda x: x[None], w_new)
+        return w_new, W, Z, gl1, leaf_sq
+
+    c = P(CLIENT_AXIS)
+    # an all-gather's result is the same on every chip, which shard_map's
+    # check cannot infer: the check is off, and w_new is chip 0's
+    sharded = jax.shard_map(local, mesh=mesh,
+                            in_specs=(c, c, c, c, c, P()),
+                            out_specs=(P() if exchange else c, c, c, c, c),
+                            check_vma=False)
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def round_(W, Z, k, mask, batches, key):
+        keys = jax.random.split(key, cst.m)
+        w_new, W, Z, gl1, leaf_sq = sharded(W, Z, mask, batches, keys, k)
+        if not exchange:
+            w_new = tmap(lambda x: x[0], w_new)
+        return w_new, W, Z, gl1, tmap(jnp.sum, leaf_sq)
+
+    return round_
+
+
+def make_sharded_objective(loss, mesh):
+    """-> jitted ``objective(w, batches)``: each chip sums its own
+    clients' losses one after another, and the chips' sums are added."""
+    from jax.sharding import PartitionSpec as P
+
+    def local(w, bs):
+        return jax.lax.psum(jnp.sum(jax.lax.map(lambda b: loss(w, b), bs)),
+                            CLIENT_AXIS)
+
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(), P(CLIENT_AXIS)),
+                                 out_specs=P()))
 
 
 class Start:
@@ -241,12 +343,21 @@ class Start:
     data, loss and initial parameters, the stacked client state, the
     experiment key and the jitted round, objective and draws.
 
+    ``chips`` is the cell's, which is the configuration's ``engine.mesh``
+    (``harness.mesh_chips``). On more than one chip the client axis of the
+    state and the batches lies over ``client_mesh`` and the round is
+    ``make_sharded_round``; on one, everything is on the default device.
+
     ``fault="half_batch"`` takes every client's loss over the first half of
-    its rows only, the mean over those.
+    its rows only, the mean over those. ``fault="no_exchange"`` (more than
+    one chip) leaves the exchange between chips out of the aggregate.
     """
 
     def __init__(self, task, cfg: dict, spec: dict, seed: int, *,
-                 lower: bool = False, fault: str | None = None):
+                 lower: bool = False, fault: str | None = None,
+                 chips: int = 1):
+        if fault == "no_exchange" and chips == 1:
+            raise ValueError("no_exchange needs more than one chip")
         self.task, self.cst = task, constants_from(spec)
         batches, params0, self.d_local = task.make_data(cfg, seed)
         if fault == "half_batch":
@@ -255,19 +366,36 @@ class Start:
         dt = task.state_dtype(lower)
         self.n_params = sum(int(np.prod(x.shape))
                             for x in jax.tree_util.tree_leaves(params0))
-        self.batches = tmap(jnp.asarray, batches)
-        self.p0 = tmap(lambda x: jnp.asarray(x).astype(dt), params0)
         m = self.cst.m
-        W = tmap(lambda x: jnp.broadcast_to(x[None], (m,) + x.shape),
-                 self.p0)
-        self.Z = tmap(lambda x: jnp.array(x, copy=True), W)
-        self.W = tmap(lambda x: jnp.array(x, copy=True), W)
+        if chips == 1:
+            self.batches = tmap(jnp.asarray, batches)
+            self.p0 = tmap(lambda x: jnp.asarray(x).astype(dt), params0)
+            W = tmap(lambda x: jnp.broadcast_to(x[None], (m,) + x.shape),
+                     self.p0)
+            self.Z = tmap(lambda x: jnp.array(x, copy=True), W)
+            self.W = tmap(lambda x: jnp.array(x, copy=True), W)
+            self.round = make_round(loss, self.cst)
+            self.objective = jax.jit(lambda w, bs: jnp.sum(
+                jax.lax.map(lambda b: loss(w, b), bs)))
+        else:
+            mesh = client_mesh(chips)
+            rep = jax.sharding.NamedSharding(mesh,
+                                             jax.sharding.PartitionSpec())
+            split = jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec(CLIENT_AXIS))
+            self.batches = jax.device_put(batches, split)
+            self.p0 = jax.device_put(
+                tmap(lambda x: jnp.asarray(x).astype(dt), params0), rep)
+            stack = jax.jit(lambda p: tmap(
+                lambda x: jnp.broadcast_to(x[None], (m,) + x.shape), p),
+                out_shardings=split)
+            self.Z, self.W = stack(self.p0), stack(self.p0)
+            self.round = make_sharded_round(
+                loss, self.cst, mesh, exchange=fault != "no_exchange")
+            self.objective = make_sharded_objective(loss, mesh)
         self.key = jax.random.PRNGKey(seed)
         self.k = jnp.asarray(0, jnp.int32)
         self.w_tau = self.p0
-        self.round = make_round(loss, self.cst)
-        self.objective = jax.jit(lambda w, bs: jnp.sum(
-            jax.lax.map(lambda b: loss(w, b), bs)))
         self.split3 = jax.jit(lambda kk: jax.random.split(kk, 3))
         # closes over the rate, not over self: a cycle would keep this
         # reference's device state alive after it returns

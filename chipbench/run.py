@@ -38,8 +38,10 @@ def fail(msg: str) -> int:
 
 
 def cell_files(manifest: dict, workload: str):
-    """-> (cell, config entry, config dict, mix dict) by name."""
-    from harness import load_json
+    """-> (cell, config entry, config dict, mix dict) by name. A cell whose
+    ``chips`` are not the chips its configuration's client axis spans
+    (``harness.mesh_chips``) is a ValueError."""
+    from harness import load_json, mesh_chips
     cells = {w["name"]: w for w in manifest["workloads"]}
     if workload not in cells:
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
@@ -47,6 +49,10 @@ def cell_files(manifest: dict, workload: str):
     entry = {c["name"]: c for c in manifest["configs"]}[cell["config"]]
     cfg = load_json(ROOT / entry["file"])
     cfg["_file"] = ROOT / entry["file"]
+    if cell["chips"] != mesh_chips(cfg):
+        raise ValueError(f"{workload} asks for {cell['chips']} chips; its "
+                         f"configuration's engine.mesh spans "
+                         f"{mesh_chips(cfg)}")
     mix_file = BENCH_DIR / "mixes" / f"{cell['traffic']}.json"
     mix = load_json(mix_file)
     mix["_file"] = mix_file
@@ -114,7 +120,7 @@ def main(argv=None) -> int:
     manifest = harness.load_json(manifest_path)
     try:
         cell, entry, cfg, mix = cell_files(manifest, args.workload)
-    except (KeyError, FileNotFoundError) as e:
+    except (KeyError, FileNotFoundError, ValueError) as e:
         return fail(str(e))
     peaks = harness.load_json(BENCH_DIR / "peaks.json")
 
@@ -151,7 +157,8 @@ def main(argv=None) -> int:
     if run["compiles_in_window"]:
         print(f"chipbench: {run['compiles_in_window']} program(s) compiled "
               "inside the window", file=sys.stderr)
-    correct, checks = harness.check(cfg, mix, args.seed, run["prog"])
+    correct, checks = harness.check(cfg, mix, args.seed, run["prog"],
+                                    cell["chips"])
     settle_cache(CACHE_DIR, args.workload)
     finite = run["f_final"] == run["f_final"] and abs(run["f_final"]) < 1e38
     failed = 0 if finite else run["rounds"]

@@ -59,10 +59,30 @@ def test_every_cell_builds_a_spec(cell):
     spec.validate()
     assert spec.engine.name == "scan"
     assert spec.engine.chunk == cfg["spec"]["engine"]["chunk"]
-    assert w["chips"] == 1
+    # a cell spans one chip or a four-chip host; four only where its
+    # configuration's client axis lies over the four, whole clients a chip
+    assert w["chips"] in (1, 4)
+    assert w["chips"] == harness.mesh_chips(cfg)
+    if w["chips"] > 1:
+        assert cfg["spec"]["engine"]["mesh"] == w["chips"]
+        assert cfg["spec"]["task"]["m"] % w["chips"] == 0
+    four = [x for x in MANIFEST["workloads"] if x["chips"] == 4]
+    assert len(four) <= max(1, len(CELLS) // 2)
     assert mix["_file"].with_suffix(".py").exists()
     for m in MANIFEST["per_layer"] + MANIFEST["end_to_end"]:
         assert (BENCH / "metrics" / f"{m['name']}.py").exists()
+
+
+def test_a_cell_whose_chips_are_not_its_mesh_is_refused():
+    def with_chips(name, chips):
+        cells = [{**w, "chips": chips} if w["name"] == name else w
+                 for w in MANIFEST["workloads"]]
+        return {**MANIFEST, "workloads": cells}
+
+    for w in MANIFEST["workloads"]:
+        other = 1 if w["chips"] == 4 else 4
+        with pytest.raises(ValueError, match="chips"):
+            bench_run.cell_files(with_chips(w["name"], other), w["name"])
 
 
 @pytest.mark.parametrize("metric", [m["name"] for m in MANIFEST["per_layer"]])
@@ -83,6 +103,11 @@ def test_flop_counts():
     assert task.flops_per_token(lm) == 1_231_763_328
     assert task.tokens_per_round(lm) == 4 * 2 * 2048
     assert task.flops_per_round(lm) == 1_231_763_328 * 16384
+    m8 = harness.load_json(BENCH / "configs" / "smollm-135m-m8.json")
+    task8 = harness.config_module(BENCH / "configs" / "smollm-135m-m8.json")
+    assert task8.n_params(m8) == task.n_params(lm)
+    assert task8.tokens_per_round(m8) == 8 * 2 * 2048
+    assert task8.flops_per_round(m8) == 2 * task.flops_per_round(lm)
     lr = harness.load_json(BENCH / "configs" / "paper-logreg.json")
     task = harness.config_module(BENCH / "configs" / "paper-logreg.json")
     assert task.flops_per_round(lr) == 2_532_432

@@ -161,7 +161,7 @@ def test_hlo_metadata_of_a_compiled_program_names_its_scopes():
 def test_a_traced_window_has_one_objective_span_per_round(tmp_path):
     """A tiny logreg window traced on the CPU: the program's host spans
     land in the profiler's trace on the window's thread, one objective
-    read a round and one dispatch a chunk; the program counts the window's
+    read and one dispatch a chunk; the program counts the window's
     device-to-host reads; the chunk's compiled text names its stages. The
     CPU trace has no device plane, so one device op spanning the window
     stands in for the device."""
@@ -173,9 +173,9 @@ def test_a_traced_window_has_one_objective_span_per_round(tmp_path):
     rounds = ev["rounds"]
     assert rounds % chunk == 0 and rounds >= chunk
     assert not list(tmp_path.rglob("*.xplane.pb"))
-    # a round's objective read, a chunk's candidate pass and broadcast
-    # stream, the summary's accuracy read
-    assert ev["host_syncs"] == rounds + 2 * rounds // chunk + 1
+    # a chunk's candidate pass, broadcast stream and batched objective
+    # read, the summary's accuracy read
+    assert ev["host_syncs"] == 3 * rounds // chunk + 1
     words = {w for p in ev["hlo_paths"].values()
              for w in phases.path_scopes(p, ("ens", "client_grad",
                                              "client_prox", "dp_noise"))}
@@ -190,7 +190,7 @@ def test_a_traced_window_has_one_objective_span_per_round(tmp_path):
                    dw // 2))
     r = phases.reduce_phases(ev["host"], device)
     spans = r["spans"]
-    assert spans["repro.run.objective"][0] == rounds
+    assert spans["repro.run.objective"][0] == rounds // chunk
     assert spans["repro.engine.dispatch"][0] == rounds // chunk
     assert spans["repro.engine.bookkeeping"][0] == rounds // chunk
     assert spans["repro.engine.candidates"][0] >= rounds // chunk

@@ -96,3 +96,68 @@ def test_union_of_unsorted_nested_intervals():
     busy, gaps = tracing.busy_and_gaps([5, 0, 2, 20], [6, 4, 3, 30], 0, 25)
     assert busy == 4 + 1 + 5
     assert gaps == [(4, 5), (6, 20)]
+
+
+def collective_trace():
+    """``trace()`` on two chips. Chip 0 adds an all-to-all 12-20 ms
+    overlapping an all-gather start/done pair 18-24 ms, and an all-reduce
+    40-45 ms inside its fusion.4; chip 1 runs the same compute one ms
+    later with a collective-permute 60-70 ms, an op of its own, and one
+    collective before the window."""
+    host, device = trace()
+    dev1 = "/device:TPU:1"
+    device = device + [
+        (dev1,) + ev[1:3] + (ev[3] + MS, ev[4]) for ev in device
+        if ev[0] == DEV]
+    device += [
+        (DEV, "XLA Ops", "%all-to-all.1 = f32[33,4] all-to-all(...)",
+         12 * MS, 8 * MS),
+        (DEV, "XLA Ops", "%all-gather-start.2 = f32[4] all-gather-start()",
+         18 * MS, 3 * MS),
+        (DEV, "XLA Ops", "%all-gather-done.2 = f32[4] all-gather-done()",
+         21 * MS, 3 * MS),
+        (DEV, "XLA Ops", "all-reduce.3", 40 * MS, 5 * MS),
+        (dev1, "XLA Ops", "%collective-permute-start.4 = f32[4] x()",
+         60 * MS, 10 * MS),
+        (dev1, "XLA Ops", "%reduce-scatter.5 = f32[1] x()", -10 * MS,
+         5 * MS),
+    ]
+    return host, device
+
+
+def test_collectives_are_a_union_per_chip_inside_busy():
+    r = tracing.reduce_events(*collective_trace())
+    assert r["chips"] == 2
+    # chip 0: [12, 24) and [40, 45) -> 17 ms; chip 1: [60, 70) -> 10 ms;
+    # the one before the window is out
+    assert r["collective_s"] == pytest.approx((0.017 + 0.010) / 2)
+    # busy: chip 0 [10, 50) + [90, 95); chip 1 [11, 51) + [60, 70) +
+    # [91, 96): overlapping ops are counted once
+    assert r["busy_s"] == pytest.approx((0.045 + 0.055) / 2)
+    assert r["collective_s"] <= r["busy_s"]
+    pct = metric_lib.collective_pct({"trace": r})
+    assert pct == pytest.approx(100 * 0.027 / 0.100)
+    assert 0 < pct <= 100
+
+
+def test_no_collective_reads_none_and_moves_no_other_key():
+    r = tracing.reduce_events(*trace())
+    assert r["collective_s"] == 0
+    assert metric_lib.collective_pct({"trace": r}) is None
+    assert metric_lib.collective_pct({"trace": None}) is None
+    # every other key as the reduction read it before collectives were
+    assert {k: v for k, v in r.items() if k != "collective_s"} == {
+        "window_s": pytest.approx(0.100), "busy_s": pytest.approx(0.045),
+        "launches": 2, "chips": 1,
+        "device_ops": [["%fusion.4 = f32[4] fusion(...)",
+                        pytest.approx(0.015)],
+                       ["%sort.2 = f32[9,576] sort(...)",
+                        pytest.approx(0.010)],
+                       ["%fusion.3 = f32[4] fusion(...)",
+                        pytest.approx(0.005)],
+                       ["%fusion.5 = f32[] fusion(...)",
+                        pytest.approx(0.005)]],
+        "idle_gaps": [["DevicePut", pytest.approx(0.040)],
+                      [tracing.HOST_PYTHON, pytest.approx(0.015)]]}
+    assert tracing.is_collective("%all-to-all.7 = f32[2] all-to-all()")
+    assert not tracing.is_collective("%fusion.1 = f32[] all-reduce()")

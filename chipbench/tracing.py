@@ -31,6 +31,9 @@ MODULES_LINE = "XLA Modules"
 HOST_PYTHON = "host python between JAX calls"
 # ops that only contain other ops: their time is their children's
 CONTAINERS = ("%while", "%conditional", "%call")
+# ops that move data between chips, with their -start / -done halves
+COLLECTIVES = ("all-to-all", "all-gather", "all-reduce", "reduce-scatter",
+               "collective-permute")
 TOP_N = 10
 NAME_CHARS = 100
 
@@ -110,14 +113,22 @@ def label_gaps(spans, gaps) -> collections.Counter:
     return idle
 
 
+def is_collective(name: str) -> bool:
+    return name.lstrip("%").startswith(COLLECTIVES)
+
+
 def reduce_events(host, device) -> dict | None:
     """-> readings of one traced window, or None when the trace holds no
-    window span or no device operation inside it."""
+    window span or no device operation inside it. ``busy_s`` and
+    ``collective_s`` are per chip, averaged over the chips: the union of
+    the window's op intervals, and of those of its collectives."""
     win = [(ln, s, s + d) for ln, n, s, d in host if n == WINDOW_SPAN]
     if not win:
         return None
     host_line, w0, w1 = win[0]
     starts, ends = {}, {}
+    coll = collections.defaultdict(lambda: (array.array("d"),
+                                            array.array("d")))
     launches = collections.Counter()
     ops = collections.Counter()
     for plane, line, name, s, d in device:
@@ -128,16 +139,21 @@ def reduce_events(host, device) -> dict | None:
             continue
         starts.setdefault(plane, array.array("d")).append(s)
         ends.setdefault(plane, array.array("d")).append(s + d)
+        if is_collective(name):
+            coll[plane][0].append(s)
+            coll[plane][1].append(s + d)
         lo, hi = max(s, w0), min(s + d, w1)
         if hi > lo and not name.startswith(CONTAINERS):
             ops[name[:NAME_CHARS]] += hi - lo
     planes = sorted(set(starts) | set(launches))
-    busy, gaps = 0.0, []
+    busy, collective, gaps = 0.0, 0.0, []
     for plane in planes:
         b, g = busy_and_gaps(starts.get(plane, []), ends.get(plane, []),
                              w0, w1)
         busy += b
         gaps += g
+        if plane in coll:
+            collective += busy_and_gaps(*coll[plane], w0, w1)[0]
     if not planes or busy <= 0:
         return None
     idle = label_gaps([(s, s + d, n) for ln, n, s, d in host
@@ -147,6 +163,7 @@ def reduce_events(host, device) -> dict | None:
     return {
         "window_s": (w1 - w0) * 1e-9,
         "busy_s": busy / n * 1e-9,
+        "collective_s": collective / n * 1e-9,
         "launches": sum(launches.values()) / n,
         "chips": n,
         "device_ops": [[k, v * 1e-9] for k, v in ops.most_common(TOP_N)],
