@@ -4,8 +4,9 @@ Each module defines ``CONFIG`` (the exact assigned full-scale config, source
 cited) and ``reduced()`` (a <=512-dim, 2-layer, <=4-expert variant of the
 same family for CPU smoke tests). ``get_config(name)`` /
 ``get_reduced(name)`` dispatch by arch id; ``ALL_ARCHS`` lists the ten
-assigned ids. FedEPM execution hints (client count m and spatial/temporal
-strategy, see core/distributed.py) live in ``fed_plan``.
+assigned ids and mellum2-12b-a2.5b, whose ``CONFIG`` is one chip's
+share of the published model. FedEPM execution hints (client count m and
+spatial/temporal strategy, see core/distributed.py) live in ``fed_plan``.
 """
 from __future__ import annotations
 
@@ -24,6 +25,7 @@ ALL_ARCHS = [
     "llava-next-34b",
     "hubert-xlarge",
     "smollm-135m",
+    "mellum2-12b-a2.5b",
 ]
 
 _MODULES = {name: "repro.configs." + name.replace("-", "_").replace(".", "_")

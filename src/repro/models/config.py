@@ -8,6 +8,20 @@ import jax.numpy as jnp
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling (HF ``rope_type: "yarn"``): frequencies blended
+    between interpolation (divided by ``factor``) and extrapolation by a
+    linear ramp over the dimensions whose wavelengths lie between
+    ``beta_fast`` and ``beta_slow`` rotations in ``original_max_position``
+    tokens; cos and sin scaled by ``attention_factor``."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
     family: str                 # dense | moe | xlstm | hybrid | vlm | audio
@@ -28,10 +42,20 @@ class ArchConfig:
     # attention extents
     attention: str = "causal"               # causal | bidirectional
     sliding_window: Optional[int] = None    # SWA width if any (mixtral: 4096)
+    # one period of the layer pattern, scanned period by period:
+    # "sliding_attention" (window sliding_window, plain rope) or
+    # "full_attention" (no window, rope scaled by ``yarn``); () = every
+    # layer alike (window sliding_window, plain rope)
+    layer_types: Tuple[str, ...] = ()
+    yarn: Optional[Yarn] = None             # full_attention layers' rope
     # MoE
     n_experts: int = 0
     top_k: int = 2
     capacity_factor: float = 1.25
+    # the experts this chip holds of the router's n_experts (expert
+    # parallelism): routed dropless, only their share computed; () = all
+    # n_experts, capacity-bounded (Mixtral)
+    experts_held: Tuple[int, ...] = ()
     # SSM / xLSTM / hybrid
     ssm_state: int = 0                      # mamba2 N
     ssm_heads: int = 0
@@ -63,7 +87,7 @@ class ArchConfig:
             mlp = d * ff * (3 if self.mlp == "swiglu" else 2)
             block = attn + mlp
         elif self.family == "moe":
-            mlp = self.n_experts * d * ff * 3 + d * self.n_experts
+            mlp = self.n_local_experts * d * ff * 3 + d * self.n_experts
             block = attn + mlp
         elif self.family == "xlstm":
             di = self.ssm_expand * d
@@ -75,6 +99,11 @@ class ArchConfig:
             block = attn + d * ff * 3
         emb = V * d * (1 if self.tie_embeddings else 2)
         return emb + L * block
+
+    @property
+    def n_local_experts(self) -> int:
+        """Experts whose weights this chip holds."""
+        return len(self.experts_held) or self.n_experts
 
     def n_active_params(self) -> int:
         if self.family != "moe":

@@ -81,21 +81,66 @@ def init(key, cfg: ArchConfig):
 # block
 # ---------------------------------------------------------------------------
 
-def _attn_full(x, p, cfg: ArchConfig, positions):
+FULL, WINDOW = "full_attention", "sliding_attention"
+
+
+def _attn_full(x, p, cfg: ArchConfig, positions, kind=None):
+    """Attention of a layer of ``kind``: FULL (no window, rope scaled by
+    ``cfg.yarn``), WINDOW (window ``cfg.sliding_window``, plain rope), or
+    None in a stack whose layers are all alike (window
+    ``cfg.sliding_window``, plain rope)."""
+    yarn = cfg.yarn if kind == FULL else None
+    window = None if kind == FULL else cfg.sliding_window
     q, k, v = qkv_proj(x, p)
     if cfg.rope_theta > 0 and cfg.attention == "causal":
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q = rope(q, positions, cfg.rope_theta, yarn)
+        k = rope(k, positions, cfg.rope_theta, yarn)
     mode = "bidirectional" if cfg.attention == "bidirectional" else "causal"
-    with jax.named_scope("attention"):
-        o = flash_attention(q, k, v, mode=mode, window=cfg.sliding_window,
+    # a windowed layer of a mixed stack has a scope of its own
+    scope = (jax.named_scope("attention_window") if kind == WINDOW
+             else jax.named_scope("attention"))
+    with scope:
+        o = flash_attention(q, k, v, mode=mode, window=window,
                             q_positions=positions, kv_positions=positions)
     return out_proj(o, p), k, v
 
 
-def block_forward(x, lp, cfg: ArchConfig, positions):
+def scan_layers(block, x, layers, cfg: ArchConfig):
+    """Apply the stacked ``layers`` (leading axis n_layers) to ``x``:
+    ``block(h, lp, kind) -> h`` per layer, each rematerialised (see
+    ``maybe_remat``). A uniform stack scans its layers; a stack with a
+    ``cfg.layer_types`` period of P kinds scans its n_layers / P periods,
+    each applying its P layers' kinds in order."""
+    kinds = cfg.layer_types or (None,)
+    if len(kinds) == 1:
+        blk = maybe_remat(lambda h, lp: block(h, lp, kinds[0]), cfg)
+
+        def body(h, lp):
+            return blk(h, lp), None
+
+        x, _ = lax.scan(body, x, layers)
+        return x
+    P = len(kinds)
+    if cfg.n_layers % P or not set(kinds) <= {FULL, WINDOW}:
+        raise ValueError(f"{cfg.name}: {cfg.n_layers} layers are not whole "
+                         f"periods of layer kinds {FULL}/{WINDOW}: {kinds}")
+    blks = [maybe_remat(lambda h, lp, kind=kind: block(h, lp, kind), cfg)
+            for kind in kinds]
+    periods = jax.tree_util.tree_map(
+        lambda a: a.reshape((a.shape[0] // P, P) + a.shape[1:]), layers)
+
+    def period(h, lp):
+        for i, blk in enumerate(blks):
+            h = blk(h, jax.tree_util.tree_map(lambda a: a[i], lp))
+        return h, None
+
+    x, _ = lax.scan(period, x, periods)
+    return x
+
+
+def block_forward(x, lp, cfg: ArchConfig, positions, kind=None):
     h = apply_norm(x, lp["ln_attn"], cfg.norm)
-    attn_out, _, _ = _attn_full(h, lp["attn"], cfg, positions)
+    attn_out, _, _ = _attn_full(h, lp["attn"], cfg, positions, kind)
     if cfg.parallel_block:
         mlp_out = apply_mlp(h, lp["mlp"], cfg.mlp)
         x = x + attn_out + mlp_out
@@ -142,13 +187,9 @@ def unembed(x, params, cfg: ArchConfig):
 def hidden(params, batch, cfg: ArchConfig):
     """Forward to the final norm, WITHOUT the unembedding (for chunked CE)."""
     x, positions = embed_inputs(params, batch, cfg)
-    blk = maybe_remat(
-        lambda h, lp: block_forward(h, lp, cfg, positions), cfg)
-
-    def body(h, lp):
-        return blk(h, lp), None
-
-    x, _ = lax.scan(body, x, params["layers"])
+    x = scan_layers(
+        lambda h, lp, kind: block_forward(h, lp, cfg, positions, kind),
+        x, params["layers"], cfg)
     return apply_norm(x, params["ln_f"], cfg.norm)
 
 

@@ -86,16 +86,44 @@ def init_norm(kind: str, dim: int, dtype=jnp.float32):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope(x, positions, theta: float = 10000.0):
-    """x: (..., T, H, D) with D even; positions: (..., T)."""
+def yarn_frequencies(head_dim: int, theta: float, yarn) -> np.ndarray:
+    """(head_dim // 2,) float32 inverse frequencies of YaRN rope (HF
+    ``_compute_yarn_parameters``, truncated correction range)."""
+    half = head_dim // 2
+    pos = theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+
+    def corr_dim(rotations):
+        return (head_dim * np.log(yarn.original_max_position
+                                  / (rotations * 2 * np.pi))
+                / (2 * np.log(theta)))
+
+    low = max(np.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(np.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    extrapolate = 1.0 - ramp
+    inv = (1.0 / (yarn.factor * pos)) * (1.0 - extrapolate) \
+        + (1.0 / pos) * extrapolate
+    return inv.astype(np.float32)
+
+
+def rope(x, positions, theta: float = 10000.0, yarn=None):
+    """x: (..., T, H, D) with D even; positions: (..., T). With ``yarn``,
+    YaRN frequencies and cos and sin scaled by its attention factor."""
     d = x.shape[-1]
     half = d // 2
-    freqs = jnp.exp(
-        -jnp.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half
-    )  # (half,)
+    if yarn is None:
+        freqs = jnp.exp(
+            -jnp.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half
+        )  # (half,)
+    else:
+        freqs = jnp.asarray(yarn_frequencies(d, theta, yarn))
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., T, half)
     cos = jnp.cos(ang)[..., None, :]  # (..., T, 1, half)
     sin = jnp.sin(ang)[..., None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = x[..., :half], x[..., half:]
     y1 = x1 * cos - x2 * sin
     y2 = x2 * cos + x1 * sin

@@ -1,4 +1,5 @@
-"""Mixture-of-Experts transformer (Mixtral family: 8 experts, top-2, SWA).
+"""Mixture-of-Experts transformer (Mixtral family: 8 experts, top-2, SWA;
+Mellum2: 64 experts, top-8, a period of windowed and full layers).
 
 Routing is capacity-bounded and sort-based (dropless up to the capacity
 factor): token assignments are argsorted by expert, positions within each
@@ -9,6 +10,14 @@ HLO_FLOPs ~= cf * top_k * (dense-equivalent FLOPs) and the roofline's
 MODEL_FLOPS/HLO_FLOPs ratio stays honest (DESIGN.md §5). Expert weights are
 (E, d, ff) with ff sharded over "model"; the dispatch buffer shards over
 ("pod","data") like the tokens it came from.
+
+A layer told which experts it holds (``cfg.experts_held``, expert
+parallelism: this chip's share of the n_experts) routes over all
+n_experts, keeps each token's top_k, and computes only the held experts'
+part of the result, DROPLESS: every held expert runs on every token,
+weighted by the token's gate (``held_moe_mlp``), so no assignment is
+lost however uneven the load. What the absent experts would add is not
+computed here; on one chip that partial result goes to the next layer.
 """
 from __future__ import annotations
 
@@ -26,7 +35,6 @@ from repro.models.layers import (
     dense_init,
     init_attention,
     init_norm,
-    maybe_remat,
     out_proj,
     qkv_proj,
     rope,
@@ -37,6 +45,15 @@ from repro.sharding.rules import constrain
 def init_moe_mlp(key, cfg: ArchConfig):
     ks = jax.random.split(key, 4)
     E, d, ff = cfg.n_experts, cfg.d_model, cfg.d_ff
+    if cfg.experts_held:
+        # the held experts only, each scaled by its own fan-in
+        H = len(cfg.experts_held)
+        return {
+            "router": dense_init(ks[0], (d, E), cfg.param_dtype),
+            "wi": dense_init(ks[1], (H, d, ff), cfg.param_dtype, d ** -0.5),
+            "wg": dense_init(ks[2], (H, d, ff), cfg.param_dtype, d ** -0.5),
+            "wo": dense_init(ks[3], (H, ff, d), cfg.param_dtype, ff ** -0.5),
+        }
     return {
         "router": dense_init(ks[0], (d, E), cfg.param_dtype),
         "wi": dense_init(ks[1], (E, d, ff), cfg.param_dtype),
@@ -74,6 +91,9 @@ def init(key, cfg: ArchConfig):
 def moe_mlp(x, p, cfg: ArchConfig):
     """x: (B, T, d) -> (B, T, d), plus aux metrics dict.
 
+    With ``cfg.experts_held``: the held experts' dropless share
+    (``held_moe_mlp``), no aux.
+
     When the ambient sharding rules map "batch" onto G > 1 mesh shards,
     routing/dispatch runs PER SHARD (vmap + spmd_axis_name) with capacity
     C/G each: tokens never cross data shards for dispatch, so the global
@@ -82,6 +102,9 @@ def moe_mlp(x, p, cfg: ArchConfig):
     absorbs the extra imbalance. Documented in DESIGN.md.)"""
     from repro.sharding.rules import batch_groups
     B, T, d = x.shape
+    if cfg.experts_held:
+        out, _ = held_moe_mlp(x.reshape(B * T, d), p, cfg)
+        return out.reshape(B, T, d), {}
     N = B * T
     xf = x.reshape(N, d)
     G, gaxes = batch_groups()
@@ -158,9 +181,56 @@ def _moe_dispatch(xf, p, cfg: ArchConfig):
     return out, aux
 
 
-def block_forward(x, lp, cfg: ArchConfig, positions):
+# ---------------------------------------------------------------------------
+# held experts: every held expert on every token, gated
+# ---------------------------------------------------------------------------
+
+def route_held(xf, router, cfg: ArchConfig):
+    """Route (N, d) tokens over all n_experts and keep each one's top_k,
+    their softmax weights renormalised over the top_k (HF's
+    ``norm_topk_prob``); -> (gates (N, held) float32, each held expert's
+    weight for each token, 0 where the token did not choose it; load
+    (held,) int32, the tokens that chose each held expert).
+
+    The logits are float32 at the highest matmul precision, so that
+    low-precision rounding flips as few top-k choices as it can, and the
+    top-k is taken of the logits: the softmax's order, without the ties of
+    probabilities that underflow to 0."""
+    logits = jnp.dot(xf.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    _, top_e = lax.top_k(logits, cfg.top_k)
+    top_p = jnp.take_along_axis(jax.nn.softmax(logits, axis=-1), top_e, -1)
+    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    chosen = top_e[..., None] == jnp.asarray(cfg.experts_held)  # (N, k, h)
+    gates = jnp.sum(jnp.where(chosen, top_p[..., None], 0.0), axis=1)
+    return gates, jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)
+
+
+def held_moe_mlp(xf, p, cfg: ArchConfig):
+    """The held experts' share of the layer for (N, d) tokens -> ((N, d),
+    load (held,) int32: the tokens each held expert serves).
+
+    Dropless with no dispatch: every held expert runs on every token, its
+    hidden activation scaled by the token's gate (0 where the token did
+    not choose it), and the held experts' down projections sum in one
+    contraction over (held, d_ff). No sort, gather or scatter runs, and
+    the cost does not move with the routing, however uneven: a sorted
+    dispatch computes only the routed rows (a top_k / n_experts share of
+    these, 1 in 8 in Mellum2's), but its rate follows the load."""
+    dt = xf.dtype
+    with jax.named_scope("moe_route"):
+        gates, load = route_held(xf, p["router"], cfg)
+    with jax.named_scope("moe_experts"):
+        h = jax.nn.silu(jnp.einsum("nd,edf->nef", xf, p["wi"].astype(dt))) \
+            * jnp.einsum("nd,edf->nef", xf, p["wg"].astype(dt))
+        out = jnp.einsum("nef,efd->nd", h * gates.astype(dt)[..., None],
+                         p["wo"].astype(dt))
+    return out, load
+
+
+def block_forward(x, lp, cfg: ArchConfig, positions, kind=None):
     h = apply_norm(x, lp["ln_attn"], cfg.norm)
-    attn_out, k, v = dense._attn_full(h, lp["attn"], cfg, positions)
+    attn_out, k, v = dense._attn_full(h, lp["attn"], cfg, positions, kind)
     x = x + attn_out
     h2 = apply_norm(x, lp["ln_mlp"], cfg.norm)
     mlp_out, aux = moe_mlp(h2, lp["moe"], cfg)
@@ -170,13 +240,9 @@ def block_forward(x, lp, cfg: ArchConfig, positions):
 
 def hidden(params, batch, cfg: ArchConfig):
     x, positions = dense.embed_inputs(params, batch, cfg)
-    blk = maybe_remat(
-        lambda h, lp: block_forward(h, lp, cfg, positions)[0], cfg)
-
-    def body(h, lp):
-        return blk(h, lp), None
-
-    x, _ = lax.scan(body, x, params["layers"])
+    x = dense.scan_layers(
+        lambda h, lp, kind: block_forward(h, lp, cfg, positions, kind)[0],
+        x, params["layers"], cfg)
     return apply_norm(x, params["ln_f"], cfg.norm)
 
 

@@ -9,7 +9,8 @@ Every family module exposes:
 
 ``hubert``-style encoder-only archs (attention="bidirectional") have no
 decode path; the registry raises for them so callers fail loudly (the
-dry-run skips decode shapes for encoder archs, see DESIGN.md §4).
+dry-run skips decode shapes for encoder archs, see DESIGN.md §4). Nor do
+stacks that mix layer kinds (``layer_types``, Mellum2): they train only.
 """
 from __future__ import annotations
 
@@ -41,7 +42,8 @@ class Model(NamedTuple):
 
     @property
     def has_decode(self) -> bool:
-        return self.cfg.attention != "bidirectional"
+        return (self.cfg.attention != "bidirectional"
+                and len(set(self.cfg.layer_types)) <= 1)
 
     @property
     def is_subquadratic(self) -> bool:
@@ -63,10 +65,12 @@ def get_model(cfg: ArchConfig) -> Model:
         return mod.apply(params, batch, cfg)
 
     def _no_decode(*a, **kw):
-        raise NotImplementedError(
-            f"{cfg.name} is encoder-only ({cfg.attention}); no decode path")
+        why = (f"encoder-only ({cfg.attention})"
+               if cfg.attention == "bidirectional" else
+               f"a stack of mixed layer kinds {cfg.layer_types}")
+        raise NotImplementedError(f"{cfg.name} is {why}; no decode path")
 
-    if cfg.attention == "bidirectional":
+    if cfg.attention == "bidirectional" or len(set(cfg.layer_types)) > 1:
         pre, dec, ids = _no_decode, _no_decode, _no_decode
     else:
         def pre(params, batch, max_len=None):

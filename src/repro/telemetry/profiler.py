@@ -31,6 +31,11 @@ DEVICE_SCOPES = (
     "ens",             # core/fedepm.py: ENS aggregation (19)
     "client_grad",     # core/fedepm.py: the vmapped client gradient (18)
     "attention",       # models/dense.py: flash attention, fwd and bwd
+    "attention_window",  # models/dense.py: a windowed layer's attention
+                         # in a stack of mixed layer kinds
+    "moe_route",       # models/moe.py: held experts' router, top-k and
+                       # gates
+    "moe_experts",     # models/moe.py: the held experts' gated matmuls
     "client_prox",     # core/fedepm.py: k0 closed-form prox steps (20)
     "dp_noise",        # core/fedepm.py, core/baselines.py: DP-noised upload
     "upload_codec",    # sim/transport.py: codec round trip
