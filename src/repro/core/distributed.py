@@ -10,9 +10,10 @@ with the single-host reference to float tolerance):
   "model" (tensor parallel inside each client). Gradients for all clients
   run concurrently (vmap over the client axis). The server step (ENS, eq.
   (19)) is the only cross-client communication:
-    * ``ens="gather"``  -- sort along the m axis; XLA all-gathers the
-      client-sharded axis (paper-faithful star transport: everyone's z to
-      one place). O(m x n) bytes received per device group.
+    * ``ens="gather"``  -- ENS along the m axis (selection or sort, see
+      ``ens_gather``); XLA gathers the client-sharded axis (paper-faithful
+      star transport: everyone's z to one place). O(m x n) bytes received
+      per device group.
     * ``ens="a2a"``     -- beyond-paper: shard_map all_to_all redistributes
       coordinates so each device group owns n/m coordinates of ALL clients,
       runs ENS locally, and all-gathers the n/m-sized aggregate. O(n) bytes
@@ -126,10 +127,12 @@ def batch_specs(batch_tree, dist: DistConfig) -> Any:
 # ---------------------------------------------------------------------------
 
 def ens_gather(Z, lam, eta, local_impl: str = "ref"):
-    """Baseline transport: sort along the (client-sharded) m axis. Under
-    pjit, XLA lowers this to an all-gather of the m axis per device group
-    -- the faithful analogue of every client uploading z_i to the server.
-    Large leaves are chunked over their layer axis inside ens_tree so the
+    """Baseline transport: ENS along the (client-sharded) m axis, leaf by
+    leaf through ens_tree, and XLA moves the client rows between device
+    groups -- the faithful analogue of every client uploading z_i to the
+    server. With m <= 8 each leaf takes the min/max selection over the
+    client rows (row slices of the sharded axis); above that it sorts
+    along the axis, in lax.map chunks over axis 1 for large leaves so the
     (2m+1)-stacked sort buffers stay bounded (see kernels/ens/ops.py).
     """
     return ens_ops.ens_tree(Z, lam, eta, impl=local_impl)

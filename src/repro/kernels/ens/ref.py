@@ -4,12 +4,15 @@ ENS solves, coordinate-wise over j in [n],
 
     w*_j = argmin_w  sum_{i=1..m} ( lam*|w - Z_ij| + (eta/2)*(w - Z_ij)^2 )
 
-Three implementations are provided:
+Four implementations are provided:
 
 ``ens_ref``     -- the production-quality jnp reference (median identity, see
                    below). O(n * m log m). This is the oracle the Pallas
-                   kernel is validated against and the jnp fallback used by
-                   the distributed runtime when kernels are disabled.
+                   kernel and ``ens_select`` are validated against, and the
+                   jnp path for many clients (``ops._SELECT_MAX_M``).
+``ens_select``  -- the same median without a sort (merge-select identity,
+                   see ``ens_select``): elementwise min/max over the m client
+                   rows and the m+1 candidate rows. The jnp path for small m.
 ``ens_oracle``  -- brute-force argmin over the full candidate set by direct
                    objective evaluation. O(n * m^2). Used only in tests as
                    an independently-correct ground truth.
@@ -49,6 +52,16 @@ def _check_2d(Z: jax.Array) -> None:
         raise ValueError(f"ENS expects Z of shape (m, n); got {Z.shape}")
 
 
+def _mean_and_offsets(Z: jax.Array, lam, eta):
+    """-> (mean (...), offs (m+1,)): the interior candidates are
+    c_a = mean + offs[a], rising in a when lam/eta >= 0."""
+    m = Z.shape[0]
+    mean = jnp.mean(Z, axis=0)                           # (...)
+    a = jnp.arange(m + 1, dtype=Z.dtype)
+    offs = (lam / eta) * (2.0 * a - m) / m              # (m+1,)
+    return mean, offs
+
+
 def ens_candidates(Z: jax.Array, lam, eta) -> jax.Array:
     """Stack the 2m+1 per-coordinate candidates: (2m+1, ...).
 
@@ -57,11 +70,9 @@ def ens_candidates(Z: jax.Array, lam, eta) -> jax.Array:
     leaf is unrepresentable and silently REPLICATES the whole tensor.)
     """
     m = Z.shape[0]
-    mean = jnp.mean(Z, axis=0, keepdims=True)           # (1, ...)
-    a = jnp.arange(m + 1, dtype=Z.dtype)
-    offs = (lam / eta) * (2.0 * a - m) / m              # (m+1,)
+    mean, offs = _mean_and_offsets(Z, lam, eta)
     offs = offs.reshape((m + 1,) + (1,) * (Z.ndim - 1))
-    cands = mean + offs                                  # (m+1, ...)
+    cands = mean[None] + offs                            # (m+1, ...)
     return jnp.concatenate([Z, cands], axis=0)
 
 
@@ -71,6 +82,76 @@ def ens_ref(Z: jax.Array, lam, eta) -> jax.Array:
     m = Z.shape[0]
     sorted_ = jnp.sort(stacked, axis=0)
     return sorted_[m]  # middle of 2m+1
+
+
+def _merge_sort_network(n: int) -> list[tuple[int, int]]:
+    """Batcher's odd-even merge sort on n = 2^k rows: compare-exchange
+    pairs (i, j), i < j, that leave row i <= row j."""
+    pairs = []
+    p = 1
+    while p < n:
+        k = p
+        while k >= 1:
+            for j in range(k % p, n - k, 2 * k):
+                for i in range(min(k, n - j - k)):
+                    if (i + j) // (2 * p) == (i + j + k) // (2 * p):
+                        pairs.append((i + j, i + j + k))
+            k //= 2
+        p *= 2
+    return pairs
+
+
+def _sorted_rows(rows: list) -> list:
+    """Sort a list of equal-shaped arrays elementwise, ascending, by a
+    compare-exchange network fixed at trace time. The network is padded to
+    a power of two with +inf rows; a pair that meets a pad changes nothing,
+    so it emits no op."""
+    m = len(rows)
+    rows = list(rows)
+    for i, j in _merge_sort_network(1 << max(m - 1, 0).bit_length()):
+        if j < m:
+            rows[i], rows[j] = (jnp.minimum(rows[i], rows[j]),
+                                jnp.maximum(rows[i], rows[j]))
+    return rows
+
+
+def ens_select(Z: jax.Array, lam, eta) -> jax.Array:
+    """ENS without a sort: the middle of the 2m+1 values by elementwise
+    min/max. Z: (m, ...) -> (...); needs lam/eta >= 0. Equal to ``ens_ref``
+    (the same float; only the sign of a zero may differ).
+
+    The merge-select identity
+    -------------------------
+    Let Xs[0] <= .. <= Xs[m-1] be the client values, sorted by a fixed
+    compare-exchange network, with Xs[m] := +inf, and C[a] = c_a the
+    candidates, which rise in a since lam/eta >= 0 (fl(mean + off) is
+    monotone in off). The middle of all 2m+1 values, the m-th smallest
+    counting from 0, is
+
+        w* = max_{i=0..m} min(Xs[i], C[m-i]).
+
+    No term exceeds it: were both Xs[i] and C[m-i] above sorted_[m], the
+    m+1 values at or below it would lie among Xs[:i] and C[:m-i], only m
+    values. One term reaches it: with i the count of client values among
+    the first m of the merged order, sorted_[m] is Xs[i] or C[m-i], and
+    the other of the two lies at or after it. Every min and max returns
+    one of its inputs, so the result is a value ``ens_ref`` sorts, not a
+    rounding of one.
+
+    The candidates are ``ens_candidates``' own (same ops, same order, see
+    ``_mean_and_offsets``), each built as a row of its own. Past the
+    mean, the whole expression is elementwise over the trailing axes: no
+    row stack and no reshape of the client axis, so XLA can fuse it into
+    passes that read Z and write w.
+    """
+    with jax.named_scope("ens_select"):
+        m = Z.shape[0]
+        mean, offs = _mean_and_offsets(Z, lam, eta)
+        xs = _sorted_rows([Z[i] for i in range(m)])
+        w = mean + offs[0]                                # i = m: Xs[m] = +inf
+        for i in range(m):
+            w = jnp.maximum(w, jnp.minimum(xs[i], mean + offs[m - i]))
+        return w
 
 
 def ens_objective(Z: jax.Array, w: jax.Array, lam, eta) -> jax.Array:

@@ -29,6 +29,8 @@ import contextlib
 
 DEVICE_SCOPES = (
     "ens",             # core/fedepm.py: ENS aggregation (19)
+    "ens_select",      # kernels/ens/ref.py: ENS's median by min/max
+                       # (m <= 8), inside "ens"; the rest of "ens" sorts
     "client_grad",     # core/fedepm.py: the vmapped client gradient (18)
     "attention",       # models/dense.py: flash attention, fwd and bwd
     "attention_window",  # models/dense.py: a windowed layer's attention

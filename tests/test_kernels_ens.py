@@ -1,5 +1,7 @@
 """ENS kernel validation: Pallas (interpret) and jnp ref vs brute-force
 oracle, plus property-based tests of the Lemma III.1/III.2 solution."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -151,3 +153,116 @@ def test_ens_tree_shapes():
     out = ops.ens_tree(tree, 0.1, 0.2, impl="ref")
     assert out["a"].shape == (3, 4)
     assert out["b"][0].shape == (7,)
+
+
+def _leaf(kind: str, shape: tuple, seed: int) -> np.ndarray:
+    rng = np.random.default_rng(seed)
+    if kind == "gauss":
+        return (rng.standard_normal(shape) * 3.0).astype(np.float32)
+    if kind == "ties":
+        return rng.integers(-2, 3, shape).astype(np.float32)
+    return rng.choice(np.array([0.0, -0.0, 1.0, -1.0], np.float32), shape)
+
+
+@pytest.mark.parametrize("m", range(1, 9))
+@pytest.mark.parametrize("trailing", [(7,), (3, 1, 5), (2, 1, 3, 4)],
+                         ids=["1d", "3d-size1-axis1", "4d"])
+@pytest.mark.parametrize("lam_eta", [(0.5, 1.0), (1e-9, 1.0), (1.0, 1e-9)],
+                         ids=["mid", "lam0", "eta0"])
+@pytest.mark.parametrize("kind", ["gauss", "ties", "signed-zeros"])
+def test_select_equals_sort(m, trailing, lam_eta, kind):
+    """The merge-select identity returns the value the sort puts in the
+    middle, exactly (assert_array_equal counts -0.0 equal to 0.0)."""
+    lam, eta = lam_eta
+    Z = jnp.asarray(_leaf(kind, (m,) + trailing, seed=m * 31 + len(trailing)))
+    np.testing.assert_array_equal(ref.ens_select(Z, lam, eta),
+                                  ref.ens_ref(Z, lam, eta))
+
+
+@pytest.mark.parametrize("m", [2, 4, 8, 50])
+def test_ens_tree_above_chunk_threshold_equals_leafwise_sort(monkeypatch, m):
+    """Leaves above ``_CHUNK_THRESHOLD``: selected whole (m <= 8), the same
+    floats as one sort per leaf; or sorted in lax.map chunks (m = 50),
+    whose per-chunk mean may sum in another order (last ulp)."""
+    monkeypatch.setattr(ops, "_CHUNK_THRESHOLD", 64)
+    rng = np.random.default_rng(m)
+    tree = {"big": jnp.asarray(rng.standard_normal((m, 6, 5, 4)), jnp.float32),
+            "emb": jnp.asarray(_leaf("ties", (m, 40, 3), m)),
+            "small": jnp.asarray(rng.standard_normal((m, 3)), jnp.float32)}
+    out = ops.ens_tree(tree, 0.3, 0.9, impl="ref")
+    for k, z in tree.items():
+        if m <= ops._SELECT_MAX_M:
+            np.testing.assert_array_equal(out[k], ref.ens_ref(z, 0.3, 0.9))
+        else:
+            np.testing.assert_allclose(out[k], ref.ens_ref(z, 0.3, 0.9),
+                                       rtol=1e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("m,sorts", [(2, False), (4, False), (8, False),
+                                     (50, True)])
+def test_ens_tree_sorts_only_above_the_selection_bound(m, sorts):
+    tree = {"a": jnp.zeros((m, 7, 3)), "b": jnp.zeros((m, 11))}
+    text = jax.jit(lambda t: ops.ens_tree(t, 0.5, 1.0)).lower(
+        tree).compile().as_text()
+    assert (re.search(r"\bsort\(", text) is not None) == sorts
+    assert ("/ens_select/" in text) != sorts
+
+
+@pytest.mark.parametrize("lam,eta,selects", [
+    (0.5, 1.0, True), (0.0, 1.0, True), (np.float32(0.5), 1.0, True),
+    (-0.5, 1.0, False), (0.5, jnp.float32(1.0), False)],
+    ids=["positive", "lam0", "numpy-scalar", "negative", "array"])
+def test_selection_needs_a_nonnegative_ratio_known_at_trace_time(
+        lam, eta, selects):
+    assert ops._selects(4, lam, eta) == selects
+    assert not ops._selects(ops._SELECT_MAX_M + 1, 0.5, 1.0)
+
+
+@pytest.mark.parametrize("m", [1, 3, 8, 9])
+def test_ens_ref_impl_follows_the_path_rule(m):
+    Z = jnp.asarray(_leaf("ties", (m, 33), m))
+    np.testing.assert_array_equal(ops.ens(Z, 0.25, 0.5, impl="ref"),
+                                  ref.ens_ref(Z, 0.25, 0.5))
+
+
+@pytest.mark.parametrize("m", range(1, 9))
+@pytest.mark.parametrize("lam_eta", [(0.5, 1.0), (1.0, 1e-9)],
+                         ids=["mid", "eta0"])
+def test_select_equals_sort_in_bfloat16(m, lam_eta):
+    """A bfloat16 leaf: the selection returns the sort's floats, in the
+    leaf's dtype."""
+    lam, eta = lam_eta
+    Z = jnp.asarray(_leaf("gauss", (m, 6, 35), m)).astype(jnp.bfloat16)
+    got, want = ref.ens_select(Z, lam, eta), ref.ens_ref(Z, lam, eta)
+    assert got.dtype == want.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("m", [1, 2, 4, 8])
+@pytest.mark.parametrize("fn,stacks", [("ens_ref", True),
+                                       ("ens_select", False)])
+def test_selection_stacks_no_rows(m, fn, stacks):
+    """The sort stacks the 2m+1 rows and sorts them; the selection builds
+    no stack and no sort, only elementwise ops past the mean."""
+    f = getattr(ref, fn)
+    text = jax.jit(lambda z: f(z, 0.5, 1.0)).lower(
+        jnp.zeros((m, 5, 3))).as_text()
+    assert ("stablehlo.concatenate" in text) == stacks
+    assert ("stablehlo.sort" in text) == stacks
+
+
+@pytest.mark.parametrize("case", ["paper-logreg", "stack-m8"])
+def test_ens_paths_script_tiny(case):
+    """``benchmarks/ens_paths.py`` at its CPU size: a row per lam/eta with
+    both paths timed; up to the selection bound the two agree."""
+    from benchmarks import ens_paths
+    name, m, shapes = next(c for c in ens_paths.cases(tiny=True)
+                           if c[0] == case)
+    rows = ens_paths.run_case(name, m, shapes, reps=1)
+    assert [r["lam_over_eta"] for r in rows] == list(ens_paths.RATIOS)
+    for r in rows:
+        assert r["select_ms"] > 0 and r["sort_ms"] > 0
+        assert r["elements"] == sum(int(np.prod(s)) for s in shapes)
+        if m <= ops._SELECT_MAX_M:
+            assert r["unequal"] == 0
